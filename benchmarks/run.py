@@ -52,6 +52,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from benchmarks.common import bits_to_accuracy, gaps, problem, write_csv
+from repro.compile_cache import use_compile_cache
 from repro.core import FedNL, RandK, RandomDithering, RankR, TopK
 from repro.core.baselines import (
     NL1,
@@ -1148,6 +1149,7 @@ BENCHES = [fig2_local, fig2_global, fig2_nl1, fig3_compression, fig4_options,
 
 
 def main() -> None:
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None,
                     help="comma-separated bench names to run")

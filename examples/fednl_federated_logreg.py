@@ -49,7 +49,8 @@ for k in (0, 2, 5, 10, 15, 20):
     print(f"  round {k:3d}   {cell.bits[k]:12.3e}   {cell.gaps[0, k]:.3e}")
 
 # --- the same spec, sharded over the mesh (core/federated.py path) ------------
-mesh = jax.make_mesh((jax.device_count(),), ("data",))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((jax.device_count(),), ("data",))
 spec_sh = ExperimentSpec("fednl", "rankr", 1, params=dict(option=2),
                          num_rounds=10, name="FedNL-sharded")
 cell_sh = Sweep([spec_sh], mesh=mesh).run(prob, x0=x0).cells[0]

@@ -50,7 +50,7 @@ def main():
                  batch=args.batch, seq=args.seq, lr=args.lr,
                  optimizer=args.optimizer, ckpt=args.ckpt,
                  refresh_every=args.refresh_every,
-                 curvature_k=args.curvature_k, hvp=args.hvp)
+                 curvature_k=args.curvature_k, hvp=args.hvp).losses
     print(f"\nloss: {hist[0]:.3f} -> {hist[-1]:.3f} over {args.steps} steps "
           f"({args.optimizer})")
 

@@ -161,7 +161,7 @@ class DtypeDiscipline(Rule):
     computation, comparisons) is documented behavior and passes because
     the taint dies at the bool/int boundary.
 
-    Scope: per-jaxpr dataflow (taint does not cross scan/pjit
+    Scope: per-jaxpr dataflow (taint does not cross scan/jit
     boundaries; the downcast and its re-entry live in the same traced
     scope in every pattern this repo contains)."""
 
@@ -221,7 +221,7 @@ class DtypeDiscipline(Rule):
 
 
 _CALLBACKS = ("pure_callback", "io_callback", "debug_callback",
-              "outside_call")
+              "debug_print", "outside_call")
 
 
 @register_rule
@@ -252,7 +252,7 @@ def _mode_is_drop(mode) -> bool:
 
 class _Slicer:
     """Backward slice over index dataflow, following values across
-    pjit/scan/cond scope boundaries where the mapping is positional."""
+    jit/scan/cond scope boundaries where the mapping is positional."""
 
     TRANSPARENT = ("reshape", "broadcast_in_dim", "convert_element_type",
                    "squeeze", "expand_dims", "transpose", "slice", "rev",
@@ -312,7 +312,7 @@ class _Slicer:
             return self.safe(prod.invars[0], frames)
         if name in self.COMBINING:
             return all(self.safe(o, frames) for o in prod.invars)
-        if name in ("pjit", "closed_call", "core_call", "scan", "while",
+        if name in ("jit", "closed_call", "core_call", "scan", "while",
                     "cond", "custom_jvp_call", "custom_vjp_call"):
             return True  # opaque producer: inconclusive, do not flag
         if name.startswith("scatter"):
@@ -348,10 +348,10 @@ class _Slicer:
 
     def _map_invar(self, scope, var, eqn):
         """Map a sub-jaxpr invar back to the producing eqn's operand
-        (positional for pjit/closed_call and scan; None elsewhere)."""
+        (positional for jit/closed_call and scan; None elsewhere)."""
         idx = list(scope.invars).index(var)
         name = eqn.primitive.name
-        if name in ("pjit", "closed_call", "core_call", "scan"):
+        if name in ("jit", "closed_call", "core_call", "scan"):
             if idx < len(eqn.invars):
                 return eqn.invars[idx]
         return None
@@ -440,14 +440,10 @@ class VmemBudget(Rule):
             total = 0
             parts = []
             for bm in gm.block_mappings:
-                elems = 1
-                for s in bm.block_shape:
-                    elems *= int(s) if isinstance(s, (int, np.integer)) \
-                        else 1
-                dtype = np.dtype(bm.array_shape_dtype.dtype)
-                total += elems * dtype.itemsize
-                parts.append(
-                    f"{tuple(bm.block_shape)}x{dtype.name}")
+                shape = tuple(int(s) for s in bm.block_aval.shape)
+                dtype = np.dtype(bm.array_aval.dtype)
+                total += int(np.prod(shape)) * dtype.itemsize
+                parts.append(f"{shape}x{dtype.name}")
             if total > budget:
                 kname = getattr(eqn.params.get("name_and_src_info"),
                                 "name", "pallas_call")

@@ -26,12 +26,8 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map as _shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-try:  # jax >= 0.6 exports shard_map at top level
-    from jax import shard_map as _shard_map
-except ImportError:  # pragma: no cover - depends on installed jax
-    from jax.experimental.shard_map import shard_map as _shard_map
 
 from .compressors import Compressor
 from .fednl import FedNL, FedNLState
@@ -55,9 +51,12 @@ def run_fednl_sharded(data: LogRegData, compressor: Compressor, mesh: Mesh,
     state_specs = FedNLState(x=P(), h_local=P(axis), h_global=P(), key=P(),
                              step=P())
 
+    # check_vma=False: on the TPU the step's compressor and aggregate
+    # lower to pallas_calls, which the varying-axes checker cannot type;
+    # the specs state every layout.
     @partial(_shard_map, mesh=mesh,
              in_specs=(state_specs, P(axis), P(axis)),
-             out_specs=state_specs)
+             out_specs=state_specs, check_vma=False)
     def sharded_step(state: FedNLState, a, b) -> FedNLState:
         grad_fn, hess_fn = local_oracles(a, b)
         alg = FedNL(grad_fn, hess_fn, compressor, alpha=alpha, option=option,

@@ -15,7 +15,8 @@ Execution paths:
 * default — vmap-over-seeds + scan-over-rounds, single process;
 * ``mesh=`` — the shard_map path of ``core/federated.py``: silo data and
   Hessian state sharded over the mesh's "data" axis, one pod runs the
-  cell (currently the plain-FedNL cells; other cells fall back to vmap).
+  cell. Only plain-FedNL cells whose silo count divides the axis can
+  shard; any other cell raises rather than running unsharded.
 
 Results come back as ``CellResult`` (stacked iterate/gap histories, the
 analytic AND measured cumulative-bits curves, per-cell ``us_per_round``,
@@ -216,7 +217,8 @@ class Sweep:
         for spec in self.specs:
             method = spec.build(oracles)
             t0 = time.perf_counter()
-            if self.mesh is not None and self._shardable(spec, problem):
+            if self.mesh is not None:
+                self._check_shardable(spec, problem)
                 xs = self._run_sharded(spec, problem, x0)
             else:
                 xs = run_cell(method, x0, n, spec.num_rounds, spec.seeds)
@@ -258,10 +260,16 @@ class Sweep:
 
     # -- shard_map path (reuses core/federated.py's mesh axis) -----------------
 
-    def _shardable(self, spec: ExperimentSpec, problem) -> bool:
+    def _check_shardable(self, spec: ExperimentSpec, problem) -> None:
         if spec.method != "fednl" or problem.get("data") is None:
-            return False
-        return int(problem["n"]) % int(self.mesh.shape[self.axis]) == 0
+            raise ValueError(
+                f"cell {spec.label!r} cannot run on the mesh: only 'fednl' "
+                "cells on a problem with 'data' shard")
+        n, extent = int(problem["n"]), int(self.mesh.shape[self.axis])
+        if n % extent:
+            raise ValueError(
+                f"cell {spec.label!r} cannot run on the mesh: {n} silos do "
+                f"not divide the {self.axis!r} axis of {extent} devices")
 
     def _run_sharded(self, spec: ExperimentSpec, problem, x0):
         from ..core.federated import run_fednl_sharded

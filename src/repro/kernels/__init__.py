@@ -16,10 +16,11 @@ Every kernel ships an ops.py (jit'd wrapper with interpret fallback on
 CPU) and a ref.py (pure-jnp oracle used by the allclose test sweeps).
 """
 
-# The per-program VMEM footprint budget every kernel dispatch honors:
-# 8 MiB of the ~16 MiB/core TPU VMEM, leaving headroom for scratch and
-# the pipeline's double buffering. Kernel ops dispatch on it (e.g.
-# scatter_accum picks single-block vs output-tiled) and the
-# ``vmem-budget`` static-analysis rule enforces it on every traced
-# ``pallas_call``'s BlockSpec footprint.
+# The per-program VMEM budget for BlockSpec blocks: 8 MiB of the 16 MiB
+# scoped VMEM limit the v5e compiler gives a kernel by default, leaving
+# the rest for in-body temporaries and the pipeline's second buffers.
+# The ``vmem-budget`` static-analysis rule enforces it on every traced
+# ``pallas_call``'s BlockSpec footprint; dispatchers whose kernels keep
+# large temporaries count them against it (scatter_accum's single-block
+# accumulator gets a quarter — see its ops.py).
 VMEM_BUDGET_BYTES = 8 * 1024 * 1024

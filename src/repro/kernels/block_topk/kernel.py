@@ -11,8 +11,12 @@ output format). ``block_topk_payload_kernel`` emits the WIRE FORMAT
 directly — per tile, k (value, in-tile flat index) pairs in flat order —
 so the compressed uplink never materializes a dense (d, d) buffer. The
 survivor compaction is scatter/sort-free: flat-order positions come from
-two triangular-matmul cumsums and the k payload slots are gathered with
-a one-hot contraction (MXU-friendly); empty slots carry index -1.
+triangular-matmul cumsums, and the payload slots are gathered one tile
+row at a time with a two-level one-hot contraction (slot = 128 * hi +
+lo, so a row costs a (kp/128, b) and a (128, b) one-hot instead of a
+(b*b, k) one — bounded VMEM at any k); empty slots carry index -1.
+Payload rows leave the kernel as (kp/128, 128) blocks per tile, kp = k
+rounded up to a lane multiple, and the wrappers crop them to k.
 
 The resulting operator is contractive with delta = k / (bm*bn) per
 Definition 3.3 (contraction holds per tile; Frobenius norm is separable
@@ -26,6 +30,11 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+_LANES = 128
+_ROW_GROUP = 8  # tile rows compacted per loop step
+_HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def _topk_tile_kernel(x_ref, o_ref, *, k: int, iters: int = 32):
@@ -86,28 +95,48 @@ def _bisect_bracket(ax: jax.Array, k: int, iters: int):
     return jax.lax.fori_loop(0, iters, body, (lo, hi))
 
 
+def _iota(shape, dim):
+    """f32 iota (the TPU lowering builds only integer iotas)."""
+    return jax.lax.broadcasted_iota(jnp.int32, shape, dim).astype(
+        jnp.float32)
+
+
+def _dot(a, b, acc=jnp.float32):
+    return jnp.dot(a, b, precision=_HIGHEST, preferred_element_type=acc)
+
+
+def _contract_rows(a, b, acc):
+    """sum_r a[r] @ b[r].T for (rows, m, b1) and (rows, n, b1) stacks: a
+    batched matmul over the row axis, contracting lanes, then a sum."""
+    out = jax.lax.dot_general(a, b, (((2,), (2,)), ((0,), (0,))),
+                              precision=_HIGHEST,
+                              preferred_element_type=acc)
+    return jnp.sum(out, axis=0)
+
+
 def _flat_positions(mask: jax.Array) -> jax.Array:
     """Flat-order exclusive position of each True entry, scatter/sort-
-    free: within-row inclusive cumsum and row-offset cumsum as
-    triangular matmuls (MXU work, no 1D scans). mask is (b0, b1) f32."""
+    free: the within-row inclusive cumsum and the row offsets are
+    triangular matmuls (MXU work, no 1D scans), every operand a full
+    (b, b) tile. mask is (b0, b1) f32."""
     b0, b1 = mask.shape
-    col = jax.lax.broadcasted_iota(jnp.float32, (b1, b1), 0)
-    incl = jnp.dot(mask, (col <= jax.lax.broadcasted_iota(
-        jnp.float32, (b1, b1), 1)).astype(jnp.float32),
-        preferred_element_type=jnp.float32)         # (b0, b1)
-    row = jax.lax.broadcasted_iota(jnp.float32, (b0, b0), 0)
-    strict_lower = (jax.lax.broadcasted_iota(
-        jnp.float32, (b0, b0), 1) < row).astype(jnp.float32)
-    row_offset = jnp.dot(strict_lower, incl[:, b1 - 1:b1],
-                         preferred_element_type=jnp.float32)  # (b0, 1)
+    col = _iota((b1, b1), 0)
+    upper = (col <= _iota((b1, b1), 1)).astype(jnp.float32)
+    incl = _dot(mask, upper)                        # (b0, b1)
+    row_count = _dot(mask, jnp.ones((b1, b1), jnp.float32))
+    row = _iota((b0, b0), 0)
+    strict_lower = (_iota((b0, b0), 1) < row).astype(jnp.float32)
+    row_offset = _dot(strict_lower, row_count)      # (b0, b1), per row
     return row_offset + incl - mask                 # (b0, b1)
 
 
-def _emit_topk_payload(x, vals_ref, idx_ref, *, k: int, iters: int = 32):
+def _emit_topk_payload(x, x_rows, vals_ref, idx_ref, pos_ref, mask_ref, *,
+                       k: int, iters: int = 32):
     """Shared payload-emission body: select the k largest-magnitude
-    entries of the in-VMEM tile ``x`` and write the (1, k) value/index
-    payload rows — used by both the plain top-k kernel and the fused
-    diff->top-k kernel."""
+    entries of the in-VMEM tile ``x`` and write the (kp/128, 128)
+    value/index payload blocks — used by both the plain top-k kernel and
+    the fused diff->top-k kernel. ``x_rows(r0, n)`` re-reads tile rows
+    [r0, r0 + n); ``pos_ref``/``mask_ref`` are (b0, b1) f32 scratch."""
     b0, b1 = x.shape
     ax = jnp.abs(x).astype(jnp.float32)
 
@@ -123,49 +152,83 @@ def _emit_topk_payload(x, vals_ref, idx_ref, *, k: int, iters: int = 32):
         tie = (ax >= lo).astype(jnp.float32) * (1.0 - strict)
 
     n_strict = jnp.sum(strict)
-    pos = jnp.where(strict > 0, _flat_positions(strict),
-                    n_strict + _flat_positions(tie))  # (b0, b1)
-    mask = strict + tie
+    pos_ref[...] = jnp.where(strict > 0, _flat_positions(strict),
+                             n_strict + _flat_positions(tie))
+    mask_ref[...] = strict + tie
 
-    flat_ids = (jax.lax.broadcasted_iota(jnp.float32, (b0, b1), 0) * b1
-                + jax.lax.broadcasted_iota(jnp.float32, (b0, b1), 1))
-
-    # one-hot slot assignment: onehot[e, s] = 1 iff entry e fills slot s;
-    # payload slots fill by a single (1, bb) @ (bb, k) dot each (tie
-    # overflow has pos >= k and never matches a slot)
-    slots = jax.lax.broadcasted_iota(jnp.float32, (b0 * b1, k), 1)
-    onehot = ((pos.reshape(b0 * b1, 1) == slots)
-              * mask.reshape(b0 * b1, 1))           # (bb, k) f32
-    # one-hot contraction is exact (each slot sums one entry + zeros);
-    # carry f64 through for f64 tiles (interpret mode), f32 otherwise
+    # slot s = 128 * hi + lo. Per tile row, entry e fills (hi_e, lo_e):
+    # hi_onehot[h, e] (with the value, id or fill flag folded in) times
+    # lo_onehot[l, e], contracted over the row, adds that row's entries
+    # to the (kp/128, 128) slot grid; rows go 8 at a time as one batched
+    # contraction. Each slot sums one entry + zeros, so the contraction
+    # is exact (f64 tiles carry f64 through, in interpret mode); tie
+    # overflow has pos >= kp and matches no slot.
+    n_hi = vals_ref.shape[0]
     acc = jnp.float64 if x.dtype == jnp.float64 else jnp.float32
-    vals = jnp.dot(x.reshape(1, b0 * b1).astype(acc), onehot.astype(acc),
-                   preferred_element_type=acc)                  # (1, k)
-    ids = jnp.dot(flat_ids.reshape(1, b0 * b1), onehot,
-                  preferred_element_type=jnp.float32)           # (1, k)
-    filled = jnp.dot(jnp.ones((1, b0 * b1), jnp.float32), onehot,
-                     preferred_element_type=jnp.float32) > 0.0  # (1, k)
+    rows = _ROW_GROUP if b0 % _ROW_GROUP == 0 else 1
+    hio = _iota((rows, n_hi, b1), 1)
+    loio = _iota((rows, _LANES, b1), 1)
+    local_ids = _iota((rows, 1, b1), 0) * b1 + _iota((rows, 1, b1), 2)
+
+    def group_body(g, carry):
+        vals, ids, filled = carry
+        r0 = g * rows
+        pos = pos_ref[pl.ds(r0, rows), :][:, None, :]   # (rows, 1, b1)
+        sel = mask_ref[pl.ds(r0, rows), :][:, None, :]
+        p_hi = jnp.floor(pos * (1.0 / _LANES))
+        p_lo = pos - _LANES * p_hi
+        hi_onehot = (p_hi == hio).astype(jnp.float32) * sel
+        lo_onehot = (p_lo == loio).astype(jnp.float32)
+        flat_ids = r0.astype(jnp.float32) * b1 + local_ids
+        xr = x_rows(r0, rows).astype(acc)[:, None, :]
+        vals = vals + _contract_rows(hi_onehot.astype(acc) * xr,
+                                     lo_onehot.astype(acc), acc)
+        ids = ids + _contract_rows(hi_onehot * flat_ids, lo_onehot,
+                                   jnp.float32)
+        filled = filled + _contract_rows(hi_onehot, lo_onehot, jnp.float32)
+        return vals, ids, filled
+
+    zeros = jnp.zeros((n_hi, _LANES), jnp.float32)
+    vals, ids, filled = jax.lax.fori_loop(
+        0, b0 // rows, group_body, (zeros.astype(acc), zeros, zeros))
 
     vals_ref[...] = vals.astype(vals_ref.dtype)
-    idx_ref[...] = jnp.where(filled, ids, -1.0).astype(jnp.int32)
+    idx_ref[...] = jnp.where(filled > 0.0, ids, -1.0).astype(jnp.int32)
 
 
-def _topk_payload_tile_kernel(x_ref, vals_ref, idx_ref, *, k: int,
-                              iters: int = 32):
-    _emit_topk_payload(x_ref[...], vals_ref, idx_ref, k=k, iters=iters)
+def _topk_payload_tile_kernel(x_ref, vals_ref, idx_ref, pos_ref, mask_ref,
+                              *, k: int, iters: int = 32):
+    _emit_topk_payload(x_ref[...], lambda r, n: x_ref[pl.ds(r, n), :],
+                       vals_ref, idx_ref, pos_ref, mask_ref, k=k,
+                       iters=iters)
 
 
 def _diff_topk_payload_tile_kernel(a_ref, b_ref, vals_ref, idx_ref, sq_ref,
-                                   *, k: int, iters: int = 32):
+                                   pos_ref, mask_ref, *, k: int,
+                                   iters: int = 32):
     """Fused uplink tile: D = a - b is formed IN VMEM, its squared
-    Frobenius partial written to the per-tile scalar cell, and its
+    Frobenius partial written to the tile's (1, 128) cell, and its
     top-k payload emitted — the dense (d, d) difference never exists in
     HBM."""
     x = a_ref[...] - b_ref[...]                     # (b0, b1), VMEM only
     acc = jnp.float64 if x.dtype == jnp.float64 else jnp.float32
     xa = x.astype(acc)
-    sq_ref[0, 0] = jnp.sum(xa * xa).astype(sq_ref.dtype)
-    _emit_topk_payload(x, vals_ref, idx_ref, k=k, iters=iters)
+    sq_ref[...] = jnp.broadcast_to(jnp.sum(xa * xa, keepdims=True),
+                                   sq_ref.shape).astype(sq_ref.dtype)
+    _emit_topk_payload(
+        x, lambda r, n: a_ref[pl.ds(r, n), :] - b_ref[pl.ds(r, n), :],
+        vals_ref, idx_ref, pos_ref, mask_ref, k=k, iters=iters)
+
+
+def _payload_specs(block: int, k: int, gn: int):
+    """Per-tile (kp/128, 128) payload blocks of a (nblocks, kp/128, 128)
+    output (full trailing dims: a legal TPU block at any k), the two
+    (block, block) f32 scratch tiles, and kp."""
+    kp = -(-k // _LANES) * _LANES
+    row = pl.BlockSpec((None, kp // _LANES, _LANES),
+                       lambda i, j: (i * gn + j, 0, 0))
+    scratch = [pltpu.VMEM((block, block), jnp.float32)] * 2
+    return row, scratch, kp
 
 
 def block_topk_payload_kernel(x: jax.Array, k: int, block: int = 128,
@@ -176,22 +239,22 @@ def block_topk_payload_kernel(x: jax.Array, k: int, block: int = 128,
     index -1. ``k`` must be <= block**2 (ops.py clamps)."""
     m, n = x.shape
     gm, gn = m // block, n // block
-    grid = (gm, gn)
+    row, scratch, kp = _payload_specs(block, k, gn)
+    slots = (gm * gn, kp // _LANES, _LANES)
     vals, idx = pl.pallas_call(
         functools.partial(_topk_payload_tile_kernel, k=k),
-        grid=grid,
+        grid=(gm, gn),
         in_specs=[pl.BlockSpec((block, block), lambda i, j: (i, j))],
-        out_specs=(
-            pl.BlockSpec((1, k), lambda i, j: (i * gn + j, 0)),
-            pl.BlockSpec((1, k), lambda i, j: (i * gn + j, 0)),
-        ),
+        out_specs=(row, row),
         out_shape=(
-            jax.ShapeDtypeStruct((gm * gn, k), x.dtype),
-            jax.ShapeDtypeStruct((gm * gn, k), jnp.int32),
+            jax.ShapeDtypeStruct(slots, x.dtype),
+            jax.ShapeDtypeStruct(slots, jnp.int32),
         ),
+        scratch_shapes=scratch,
         interpret=interpret,
     )(x)
-    return vals, idx
+    return (vals.reshape(gm * gn, kp)[:, :k],
+            idx.reshape(gm * gn, kp)[:, :k])
 
 
 def diff_topk_payload_kernel(a: jax.Array, b: jax.Array, k: int,
@@ -205,23 +268,25 @@ def diff_topk_payload_kernel(a: jax.Array, b: jax.Array, k: int,
     round-trips through HBM."""
     m, n = a.shape
     gm, gn = m // block, n // block
-    grid = (gm, gn)
     tile = pl.BlockSpec((block, block), lambda i, j: (i, j))
-    row = pl.BlockSpec((1, k), lambda i, j: (i * gn + j, 0))
+    row, scratch, kp = _payload_specs(block, k, gn)
+    slots = (gm * gn, kp // _LANES, _LANES)
     acc = jnp.float64 if a.dtype == jnp.float64 else jnp.float32
     vals, idx, sq = pl.pallas_call(
         functools.partial(_diff_topk_payload_tile_kernel, k=k),
-        grid=grid,
+        grid=(gm, gn),
         in_specs=[tile, tile],
         out_specs=(
             row, row,
-            pl.BlockSpec((1, 1), lambda i, j: (i * gn + j, 0)),
+            pl.BlockSpec((None, 1, _LANES), lambda i, j: (i * gn + j, 0, 0)),
         ),
         out_shape=(
-            jax.ShapeDtypeStruct((gm * gn, k), a.dtype),
-            jax.ShapeDtypeStruct((gm * gn, k), jnp.int32),
-            jax.ShapeDtypeStruct((gm * gn, 1), acc),
+            jax.ShapeDtypeStruct(slots, a.dtype),
+            jax.ShapeDtypeStruct(slots, jnp.int32),
+            jax.ShapeDtypeStruct((gm * gn, 1, _LANES), acc),
         ),
+        scratch_shapes=scratch,
         interpret=interpret,
     )(a, b)
-    return vals, idx, sq
+    return (vals.reshape(gm * gn, kp)[:, :k],
+            idx.reshape(gm * gn, kp)[:, :k], sq[:, :, 0])

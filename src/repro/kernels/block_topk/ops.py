@@ -119,12 +119,18 @@ def _diff_topk_payload_impl(a, b, k: int, block: int, use_pallas: bool,
     b = b.astype(dt)
     m, n = a.shape
     pm, pn = (-m) % block, (-n) % block
+    ap, bp = a, b
     if pm or pn:
-        a = jnp.pad(a, ((0, pm), (0, pn)))
-        b = jnp.pad(b, ((0, pm), (0, pn)))
+        ap = jnp.pad(a, ((0, pm), (0, pn)))
+        bp = jnp.pad(b, ((0, pm), (0, pn)))
     if use_pallas:
-        vals, idx, sq = diff_topk_payload_kernel(a, b, k=k, block=block,
+        vals, idx, sq = diff_topk_payload_kernel(ap, bp, k=k, block=block,
                                                  interpret=interpret)
-    else:
-        vals, idx, sq = diff_topk_payload_ref(a, b, k=k, block=block)
-    return vals, idx, jnp.sum(sq)
+        return vals, idx, jnp.sum(sq)
+    # off the kernel the norm is the plain sum over the unpadded diff:
+    # bitwise what the unfused uplink computes, so the two paths agree
+    # exactly (FedNL's tie-broken selections amplify a one-ulp l^k
+    # difference into a different trajectory)
+    vals, idx, _ = diff_topk_payload_ref(ap, bp, k=k, block=block)
+    d = a - b
+    return vals, idx, jnp.sum(d * d)

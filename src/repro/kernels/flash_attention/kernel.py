@@ -30,10 +30,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, bq: int, bk: int,
 
     def body(j, carry):
         m, l, acc = carry
-        k = pl.load(k_ref, (pl.dslice(j * bk, bk), slice(None))) \
-            .astype(jnp.float32)                           # (bk, hd)
-        v = pl.load(v_ref, (pl.dslice(j * bk, bk), slice(None))) \
-            .astype(jnp.float32)
+        k = k_ref[pl.ds(j * bk, bk), :].astype(jnp.float32)  # (bk, hd)
+        v = v_ref[pl.ds(j * bk, bk), :].astype(jnp.float32)
         s = q @ k.T                                        # (bq, bk)
         k_pos = j * bk + jnp.arange(bk)
         mask = k_pos[None, :] <= q_pos[:, None]

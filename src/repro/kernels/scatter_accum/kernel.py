@@ -8,18 +8,25 @@ ONE dense accumulator and scatter every silo's pairs into it.
 
 TPU VPUs have no native scatter, so the scatter is recast as MXU work:
 for a chunk of entries, build two one-hot matrices from the decomposed
-(row, col) indices — R[e, r] = [row_e == r] with the value folded in,
-C[e, c] = [col_e == c] — and the chunk's dense contribution is the
-matmul R^T @ C (each output cell sums exactly the entries addressing
+(row, col) indices — R[r, e] = [row_e == r] with the value folded in,
+C[c, e] = [col_e == c] — and the chunk's dense contribution is the
+matmul R @ C^T (each output cell sums exactly the entries addressing
 it, so accumulation of duplicate indices is automatic and exact in the
-accumulate dtype). Payload padding (index -1) yields row_e = -1, which
-matches no row one-hot and contributes zero.
+accumulate dtype; the matmul runs at HIGHEST precision so f32 values
+pass the MXU unrounded). Payload padding (index -1) yields row_e = -1,
+which matches no row one-hot and contributes zero.
 
-``scatter_accum_kernel``: global flat indices, grid over (silo, chunk)
-programs all revisiting the same full-matrix output block (init at
-program 0, accumulate after) — the standard Pallas revisiting-output
-reduction. Fits VMEM for d up to ~1500 f32 only; ops.py dispatches to
-it when the whole accumulator fits a VMEM budget.
+The pair stream reaches every kernel as (rows, ck) arrays cut into
+8-row blocks (one f32 sublane tile — the TPU lowering needs the last
+two block dims divisible by (8, 128) or equal to the array's); each
+program walks its 8 chunk rows in order, so the per-cell add sequence
+is the same as one chunk per program.
+
+``scatter_accum_kernel``: global flat indices, grid over pair-stream
+row blocks, all programs revisiting the same full-matrix output block
+(init at program 0, accumulate after) — the standard Pallas
+revisiting-output reduction. ops.py dispatches to it only while the
+whole accumulator fits the VMEM budget.
 
 ``scatter_accum_tiled_kernel``: the same chunked pair stream, but the
 output is a 2-D grid of (tm, tn) tiles with the chunk axis innermost —
@@ -32,7 +39,7 @@ memory trade of a tiled scatter (the one-hot matmuls are MXU work
 either way).
 
 ``block_scatter_accum_kernel``: in-tile indices, one program per output
-tile, contraction over all n*k of that tile's pairs in one matmul pair.
+tile, the tile's n silo payload rows contracted silo by silo.
 """
 
 from __future__ import annotations
@@ -43,24 +50,37 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+_ROWS = 8  # pair-stream chunk rows per grid program (one sublane tile)
+
 
 def _acc_dtype(dtype):
     return jnp.float64 if dtype == jnp.float64 else jnp.float32
 
 
+def _pad_rows(values, indices):
+    """Pad the (nchunks, ck) pair stream to a whole number of 8-row
+    blocks with inert (0, -1) rows."""
+    pad = (-values.shape[0]) % _ROWS
+    if not pad:
+        return values, indices
+    return (jnp.pad(values, ((0, pad), (0, 0))),
+            jnp.pad(indices, ((0, pad), (0, 0)), constant_values=-1))
+
+
 def _onehot_contribution(vals, rows, cols, d0: int, d1: int, acc):
     """Dense (d0, d1) sum of entries vals[e] at (rows[e], cols[e]) via
-    two one-hot matmuls; negative rows match nothing (padding)."""
+    two one-hot matmuls; negative rows match nothing (padding). All
+    three are (1, ck) rows: the one-hots broadcast them down sublanes,
+    so no lane-to-sublane relayout is needed."""
     ck = vals.shape[-1]
-    r2 = rows.reshape(ck, 1)
-    c2 = cols.reshape(ck, 1)
-    rio = jax.lax.broadcasted_iota(jnp.int32, (ck, d0), 1)
-    cio = jax.lax.broadcasted_iota(jnp.int32, (ck, d1), 1)
-    r_onehot = (r2 == rio).astype(acc) * vals.reshape(ck, 1).astype(acc)
-    c_onehot = (c2 == cio).astype(acc)
+    rio = jax.lax.broadcasted_iota(jnp.int32, (d0, ck), 0)
+    cio = jax.lax.broadcasted_iota(jnp.int32, (d1, ck), 0)
+    r_onehot = (rows == rio).astype(acc) * vals.astype(acc)   # (d0, ck)
+    c_onehot = (cols == cio).astype(acc)                      # (d1, ck)
     return jax.lax.dot_general(
         r_onehot, c_onehot,
-        dimension_numbers=(((0,), (0,)), ((), ())),
+        dimension_numbers=(((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=acc)                     # (d0, d1)
 
 
@@ -76,7 +96,7 @@ def _mirror_vals(vals, rows, cols):
 
 def _chunk_contribution(vals, idx, *, d1: int, row0, col0, tm: int,
                         tn: int, symmetric: bool):
-    """Dense (tm, tn) window contribution of one (1, ck) pair chunk.
+    """Dense (tm, tn) window contribution of one (1, ck) pair chunk row.
 
     ``row0``/``col0`` shift into window-local coordinates (0 for the
     single-block kernel, the tile origin for the tiled one): entries
@@ -95,22 +115,36 @@ def _chunk_contribution(vals, idx, *, d1: int, row0, col0, tm: int,
     return contrib
 
 
+def _accumulate_rows(vals_ref, idx_ref, out_ref, *, d1: int, row0, col0,
+                     symmetric: bool):
+    """Add each chunk row of this program's (8, ck) pair block to the
+    resident output block, in row order."""
+    tm, tn = out_ref.shape
+
+    def body(j, carry):
+        contrib = _chunk_contribution(vals_ref[pl.ds(j, 1), :],
+                                      idx_ref[pl.ds(j, 1), :], d1=d1,
+                                      row0=row0, col0=col0, tm=tm, tn=tn,
+                                      symmetric=symmetric)
+        out_ref[...] += contrib.astype(out_ref.dtype)
+        return carry
+
+    jax.lax.fori_loop(0, vals_ref.shape[0], body, 0)
+
+
 def _scatter_accum_tile_kernel(vals_ref, idx_ref, out_ref, *, d1: int,
                                symmetric: bool = False):
-    """One (value, index) chunk of one silo; all programs revisit the
-    same full-matrix out block. ``d1`` is the UNPADDED column count the
-    flat indices were built against."""
+    """One 8-row block of (value, index) chunks; all programs revisit
+    the same full-matrix out block. ``d1`` is the UNPADDED column count
+    the flat indices were built against."""
     i = pl.program_id(0)
 
     @pl.when(i == 0)
     def _():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    d0p, d1p = out_ref.shape
-    contrib = _chunk_contribution(vals_ref[...], idx_ref[...], d1=d1,
-                                  row0=0, col0=0, tm=d0p, tn=d1p,
-                                  symmetric=symmetric)
-    out_ref[...] += contrib.astype(out_ref.dtype)
+    _accumulate_rows(vals_ref, idx_ref, out_ref, d1=d1, row0=0, col0=0,
+                     symmetric=symmetric)
 
 
 def _scatter_accum_tile_init_kernel(vals_ref, idx_ref, init_ref, out_ref,
@@ -125,11 +159,8 @@ def _scatter_accum_tile_init_kernel(vals_ref, idx_ref, init_ref, out_ref,
     def _():
         out_ref[...] = init_ref[...]
 
-    d0p, d1p = out_ref.shape
-    contrib = _chunk_contribution(vals_ref[...], idx_ref[...], d1=d1,
-                                  row0=0, col0=0, tm=d0p, tn=d1p,
-                                  symmetric=symmetric)
-    out_ref[...] += contrib.astype(out_ref.dtype)
+    _accumulate_rows(vals_ref, idx_ref, out_ref, d1=d1, row0=0, col0=0,
+                     symmetric=symmetric)
 
 
 def scatter_accum_kernel(values: jax.Array, indices: jax.Array,
@@ -138,23 +169,23 @@ def scatter_accum_kernel(values: jax.Array, indices: jax.Array,
                          symmetric: bool = False,
                          init: jax.Array | None = None) -> jax.Array:
     """values/indices: (nchunks, ck) — silo payloads flattened into
-    fixed-size chunks (ops.py pads with value 0 / index -1). Returns the
+    fixed-size chunks (ops.py pads with value 0 / index -1; the chunk
+    rows are padded here to whole 8-row blocks). Returns the
     (d0p, d1p) = ``out_shape`` dense SUM; ``d1`` is the unpadded column
     count of the matrix the flat indices address. ``symmetric`` adds
     each off-diagonal entry's mirror in the same pass (lower-triangular
     payloads: the fused symmetric-TopK server sum). ``init`` seeds the
     accumulator with a prior (d0p, d1p) partial sum (the streamed path's
     running total) instead of zeros."""
-    nchunks, ck = values.shape
+    values, indices = _pad_rows(values, indices)
+    nrows, ck = values.shape
+    pairs = pl.BlockSpec((_ROWS, ck), lambda i: (i, 0))
     if init is None:
         return pl.pallas_call(
             functools.partial(_scatter_accum_tile_kernel, d1=d1,
                               symmetric=symmetric),
-            grid=(nchunks,),
-            in_specs=[
-                pl.BlockSpec((1, ck), lambda i: (i, 0)),
-                pl.BlockSpec((1, ck), lambda i: (i, 0)),
-            ],
+            grid=(nrows // _ROWS,),
+            in_specs=[pairs, pairs],
             out_specs=pl.BlockSpec(out_shape, lambda i: (0, 0)),
             out_shape=jax.ShapeDtypeStruct(out_shape, values.dtype),
             interpret=interpret,
@@ -162,12 +193,8 @@ def scatter_accum_kernel(values: jax.Array, indices: jax.Array,
     return pl.pallas_call(
         functools.partial(_scatter_accum_tile_init_kernel, d1=d1,
                           symmetric=symmetric),
-        grid=(nchunks,),
-        in_specs=[
-            pl.BlockSpec((1, ck), lambda i: (i, 0)),
-            pl.BlockSpec((1, ck), lambda i: (i, 0)),
-            pl.BlockSpec(out_shape, lambda i: (0, 0)),
-        ],
+        grid=(nrows // _ROWS,),
+        in_specs=[pairs, pairs, pl.BlockSpec(out_shape, lambda i: (0, 0))],
         out_specs=pl.BlockSpec(out_shape, lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct(out_shape, values.dtype),
         interpret=interpret,
@@ -176,11 +203,12 @@ def scatter_accum_kernel(values: jax.Array, indices: jax.Array,
 
 def _scatter_accum_tiled_tile_kernel(vals_ref, idx_ref, out_ref, *, d1: int,
                                      symmetric: bool = False):
-    """One (row-tile, col-tile, chunk) program: contribute this chunk's
-    in-window entries to the (tm, tn) output tile. The chunk axis is the
-    innermost grid dim, so each output tile is revisited consecutively
-    over the whole (silo, chunk) pair stream while staying resident in
-    VMEM — the accumulator never exists as one full (d0, d1) block."""
+    """One (row-tile, col-tile, chunk-block) program: contribute this
+    block's in-window entries to the (tm, tn) output tile. The chunk
+    axis is the innermost grid dim, so each output tile is revisited
+    consecutively over the whole (silo, chunk) pair stream while staying
+    resident in VMEM — the accumulator never exists as one full
+    (d0, d1) block."""
     c = pl.program_id(2)
 
     @pl.when(c == 0)
@@ -188,11 +216,9 @@ def _scatter_accum_tiled_tile_kernel(vals_ref, idx_ref, out_ref, *, d1: int,
         out_ref[...] = jnp.zeros_like(out_ref)
 
     tm, tn = out_ref.shape
-    contrib = _chunk_contribution(vals_ref[...], idx_ref[...], d1=d1,
-                                  row0=pl.program_id(0) * tm,
-                                  col0=pl.program_id(1) * tn,
-                                  tm=tm, tn=tn, symmetric=symmetric)
-    out_ref[...] += contrib.astype(out_ref.dtype)
+    _accumulate_rows(vals_ref, idx_ref, out_ref, d1=d1,
+                     row0=pl.program_id(0) * tm,
+                     col0=pl.program_id(1) * tn, symmetric=symmetric)
 
 
 def _scatter_accum_tiled_tile_init_kernel(vals_ref, idx_ref, init_ref,
@@ -209,11 +235,9 @@ def _scatter_accum_tiled_tile_init_kernel(vals_ref, idx_ref, init_ref,
         out_ref[...] = init_ref[...]
 
     tm, tn = out_ref.shape
-    contrib = _chunk_contribution(vals_ref[...], idx_ref[...], d1=d1,
-                                  row0=pl.program_id(0) * tm,
-                                  col0=pl.program_id(1) * tn,
-                                  tm=tm, tn=tn, symmetric=symmetric)
-    out_ref[...] += contrib.astype(out_ref.dtype)
+    _accumulate_rows(vals_ref, idx_ref, out_ref, d1=d1,
+                     row0=pl.program_id(0) * tm,
+                     col0=pl.program_id(1) * tn, symmetric=symmetric)
 
 
 def scatter_accum_tiled_kernel(values: jax.Array, indices: jax.Array,
@@ -232,19 +256,19 @@ def scatter_accum_tiled_kernel(values: jax.Array, indices: jax.Array,
     each output tile from the matching tile of a prior (d0p, d1p)
     partial sum (the streamed path's running total) instead of zeros.
     """
-    nchunks, ck = values.shape
+    values, indices = _pad_rows(values, indices)
+    nrows, ck = values.shape
     d0p, d1p = (int(s) for s in out_shape)
     tm, tn = (int(t) for t in tile)
     assert d0p % tm == 0 and d1p % tn == 0, (out_shape, tile)
+    grid = (d0p // tm, d1p // tn, nrows // _ROWS)
+    pairs = pl.BlockSpec((_ROWS, ck), lambda i, j, c: (c, 0))
     if init is None:
         return pl.pallas_call(
             functools.partial(_scatter_accum_tiled_tile_kernel, d1=d1,
                               symmetric=symmetric),
-            grid=(d0p // tm, d1p // tn, nchunks),
-            in_specs=[
-                pl.BlockSpec((1, ck), lambda i, j, c: (c, 0)),
-                pl.BlockSpec((1, ck), lambda i, j, c: (c, 0)),
-            ],
+            grid=grid,
+            in_specs=[pairs, pairs],
             out_specs=pl.BlockSpec((tm, tn), lambda i, j, c: (i, j)),
             out_shape=jax.ShapeDtypeStruct((d0p, d1p), values.dtype),
             interpret=interpret,
@@ -252,12 +276,9 @@ def scatter_accum_tiled_kernel(values: jax.Array, indices: jax.Array,
     return pl.pallas_call(
         functools.partial(_scatter_accum_tiled_tile_init_kernel, d1=d1,
                           symmetric=symmetric),
-        grid=(d0p // tm, d1p // tn, nchunks),
-        in_specs=[
-            pl.BlockSpec((1, ck), lambda i, j, c: (c, 0)),
-            pl.BlockSpec((1, ck), lambda i, j, c: (c, 0)),
-            pl.BlockSpec((tm, tn), lambda i, j, c: (i, j)),
-        ],
+        grid=grid,
+        in_specs=[pairs, pairs,
+                  pl.BlockSpec((tm, tn), lambda i, j, c: (i, j))],
         out_specs=pl.BlockSpec((tm, tn), lambda i, j, c: (i, j)),
         out_shape=jax.ShapeDtypeStruct((d0p, d1p), values.dtype),
         interpret=interpret,
@@ -265,18 +286,21 @@ def scatter_accum_tiled_kernel(values: jax.Array, indices: jax.Array,
 
 
 def _block_scatter_tile_kernel(vals_ref, idx_ref, out_ref, *, block: int):
-    """One output tile: scatter all n silos' k pairs for this tile in a
-    single one-hot matmul pair (contraction over n*k)."""
-    vals = vals_ref[...]                                # (n, 1, k)
-    idx = idx_ref[...]                                  # (n, 1, k) int32
-    n, _, k = vals.shape
-    flat_v = vals.reshape(1, n * k)
-    flat_i = idx.reshape(1, n * k)
-    rows = flat_i // block                              # -1 -> -1 (no match)
-    cols = flat_i - rows * block
-    acc = _acc_dtype(vals.dtype)
-    contrib = _onehot_contribution(flat_v, rows, cols, block, block, acc)
-    out_ref[...] = contrib.astype(out_ref.dtype)
+    """One output tile: scatter the n silos' k pairs for this tile, one
+    one-hot matmul pair per silo payload row, in silo order."""
+    acc = _acc_dtype(vals_ref.dtype)
+    out_ref[...] = jnp.zeros_like(out_ref)
+
+    def body(i, carry):
+        idx = idx_ref[pl.ds(i, 1), :]                   # (1, k) int32
+        rows = idx // block                             # -1 -> -1 (no match)
+        cols = idx - rows * block
+        contrib = _onehot_contribution(vals_ref[pl.ds(i, 1), :], rows,
+                                       cols, block, block, acc)
+        out_ref[...] += contrib.astype(out_ref.dtype)
+        return carry
+
+    jax.lax.fori_loop(0, vals_ref.shape[0], body, 0)
 
 
 def block_scatter_accum_kernel(values: jax.Array, indices: jax.Array,
@@ -288,13 +312,15 @@ def block_scatter_accum_kernel(values: jax.Array, indices: jax.Array,
     gm, gn = (int(g) for g in grid)
     n, nblk, k = values.shape
     assert nblk == gm * gn, (nblk, grid)
+    # tile-major, so one program's block is the whole (n, k) payload
+    # slab of its tile (full trailing dims: a legal TPU block)
+    values = jnp.swapaxes(values, 0, 1)
+    indices = jnp.swapaxes(indices, 0, 1)
+    tile_pairs = pl.BlockSpec((None, n, k), lambda i, j: (i * gn + j, 0, 0))
     return pl.pallas_call(
         functools.partial(_block_scatter_tile_kernel, block=block),
         grid=(gm, gn),
-        in_specs=[
-            pl.BlockSpec((n, 1, k), lambda i, j: (0, i * gn + j, 0)),
-            pl.BlockSpec((n, 1, k), lambda i, j: (0, i * gn + j, 0)),
-        ],
+        in_specs=[tile_pairs, tile_pairs],
         out_specs=pl.BlockSpec((block, block), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((gm * block, gn * block),
                                        values.dtype),

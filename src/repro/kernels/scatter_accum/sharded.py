@@ -31,12 +31,8 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map as _shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-try:  # jax >= 0.6 exports shard_map at top level
-    from jax import shard_map as _shard_map
-except ImportError:  # pragma: no cover - depends on installed jax
-    from jax.experimental.shard_map import shard_map as _shard_map
 
 from .ops import scatter_accumulate
 
@@ -107,9 +103,9 @@ def sharded_scatter_accumulate(values: jax.Array, indices: jax.Array,
                                   interpret=interpret, tile=tile,
                                   chunk=chunk)
 
-    # check_rep=False: the per-device body may lower to a pallas_call,
-    # which the replication checker has no rule for; the out_specs
+    # check_vma=False: the per-device body may lower to a pallas_call,
+    # which the varying-axes checker has no rule for; the out_specs
     # already state the (axis, None) layout exactly.
     return _shard_map(window, mesh=mesh, in_specs=(P(), P()),
                       out_specs=P(axis, None),
-                      check_rep=False)(values, indices)
+                      check_vma=False)(values, indices)

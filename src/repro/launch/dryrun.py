@@ -114,8 +114,6 @@ def _lower_one(cfg, shape, mesh, optimizer: str, unroll: bool,
 
 def _compiled_costs(compiled, chips):
     cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):  # older jax: one dict per computation
-        cost = cost[0] if cost else {}
     coll = collective_bytes(compiled.as_text())
     return {
         "flops": float(cost.get("flops", 0.0)),

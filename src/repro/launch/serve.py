@@ -12,6 +12,7 @@ import time
 import jax
 import jax.numpy as jnp
 
+from repro.compile_cache import use_compile_cache
 from repro.configs import ARCHS, get_config
 from repro.launch.mesh import make_host_mesh
 from repro.launch.sharding import make_activation_sharder, make_layer_param_constrainer
@@ -67,6 +68,7 @@ def generate(arch: str, smoke: bool = True, batch: int = 4,
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCHS, default="qwen2-0.5b")
     ap.add_argument("--smoke", action="store_true", default=True)

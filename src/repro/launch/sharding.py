@@ -169,11 +169,9 @@ def opt_state_shardings(state_shape: Any, params: Any, mesh: Mesh,
     rep = NamedSharding(mesh, P())
 
     def field(sub):
-        try:
-            mirrors = (jax.tree.structure(sub) == pdef and
-                       [x.shape for x in jax.tree.leaves(sub)] == pshapes)
-        except Exception:
-            mirrors = False
+        mirrors = (jax.tree.structure(sub) == pdef and
+                   [getattr(x, "shape", None)
+                    for x in jax.tree.leaves(sub)] == pshapes)
         if mirrors:
             return pspecs
         return jax.tree.map(lambda _: rep, sub)

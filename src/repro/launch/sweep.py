@@ -58,6 +58,9 @@ def main(argv=None) -> int:
 
     import jax
 
+    from ..compile_cache import use_compile_cache
+
+    use_compile_cache()
     if args.x64:
         jax.config.update("jax_enable_x64", True)
 
@@ -83,7 +86,9 @@ def main(argv=None) -> int:
     ]
     mesh = None
     if args.sharded:
-        mesh = jax.make_mesh((jax.device_count(),), ("data",))
+        from .mesh import make_mesh
+
+        mesh = make_mesh((jax.device_count(),), ("data",))
 
     x0 = prob["xstar"] + 0.05 * jax.random.normal(
         jax.random.PRNGKey(1), (prob["d"],))

@@ -12,10 +12,12 @@ from __future__ import annotations
 
 import argparse
 import time
+from typing import Any, Callable, NamedTuple
 
 import jax
 
 from repro.checkpoint import save as save_ckpt
+from repro.compile_cache import use_compile_cache
 from repro.configs import ARCHS, get_config
 from repro.data.tokens import TokenPipeline
 from repro.launch.mesh import make_host_mesh
@@ -42,6 +44,19 @@ def add_modality_inputs(batch, cfg, step: int):
     return batch
 
 
+class TrainRun(NamedTuple):
+    """What a ``train`` call leaves behind: the per-step losses, how many
+    steps refreshed the curvature, the wire bits one refresh ships (0
+    for first-order optimizers), the jitted step and the final state
+    it was last called with."""
+    losses: list
+    refreshes: int
+    curv_bits: int
+    step: Callable
+    params: Any
+    opt_state: Any
+
+
 def train(arch: str, smoke: bool = True, steps: int = 20, batch: int = 8,
           seq: int = 128, lr: float = 3e-4, optimizer: str = "adamw",
           microbatches: int = 1, log_every: int = 10, ckpt: str | None = None,
@@ -57,7 +72,7 @@ def train(arch: str, smoke: bool = True, steps: int = 20, batch: int = 8,
 
     opt_kw = {}
     if optimizer == "fednl":
-        opt_kw = dict(k_per_block=curvature_k,
+        opt_kw = dict(k_per_block=curvature_k, mesh=mesh,
                       curvature="hutchinson" if hvp else "fisher")
     opt = make_optimizer(optimizer, lr, **opt_kw)
     # second-order curvature state (and first-order moments) carry the
@@ -68,10 +83,11 @@ def train(arch: str, smoke: bool = True, steps: int = 20, batch: int = 8,
         state_shape, params, mesh, cfg))(params)
 
     # every shard on the mesh data axis plays one FedNL silo for the
-    # curvature observations (when the batch divides across them)
+    # curvature observations
     n_silos = dict(zip(mesh.axis_names, mesh.devices.shape)).get("data", 1)
-    if batch % max(n_silos, 1):
-        n_silos = 1
+    if batch % n_silos:
+        raise ValueError(f"batch {batch} does not divide the mesh data "
+                         f"axis of {n_silos} devices")
     step_fn = jax.jit(make_train_step(
         model, opt, microbatches=microbatches, refresh_every=refresh_every,
         n_silos=n_silos, hvp=hvp, probe_seed=seed))
@@ -105,10 +121,12 @@ def train(arch: str, smoke: bool = True, steps: int = 20, batch: int = 8,
     if ckpt:
         save_ckpt(ckpt, {"params": params}, step=steps)
         print(f"checkpoint -> {ckpt}")
-    return history
+    return TrainRun(history, refreshes, curv_bits, step_fn, params,
+                    opt_state)
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCHS, default="qwen2-0.5b")
     ap.add_argument("--smoke", action="store_true", default=True)

@@ -37,7 +37,11 @@ a per-silo dense decompression round-trip. When ``observations`` carry
 a leading silo axis (one observation per silo — the paper's placement)
 each silo compresses its own diff and H is updated from the server-side
 payload-space mean — the same aggregation subsystem the core methods
-use.
+use. On a multi-device ``mesh`` the kernels run inside ``shard_map``
+(the TPU compiler cannot partition a Pallas kernel itself): each device
+compresses the silos of its ``data`` shard when the silo count matches
+that axis, against the replicated H, and the server mean is computed
+on every device from the gathered payloads.
 
 Update rule per tensor (Option-2 Newton-type step, diagonal solve):
 
@@ -59,6 +63,7 @@ from typing import Any, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.core.compressors import BlockSparsePayload, BlockTopK, BlockTopKThreshold
 from repro.kernels.block_topk import block_topk_payload, diff_topk_payload
@@ -104,6 +109,7 @@ class FedNLPrecondOptimizer:
     curvature: str = "fisher"          # fisher | hutchinson
     selector: str = "threshold"        # threshold (bisection) | sort
     use_pallas: Optional[bool] = None  # None = auto (Pallas ops on TPU)
+    mesh: Any = None                   # device mesh the train step runs on
 
     def _k(self) -> int:
         return min(self.k_per_block, self.block * self.block)
@@ -173,6 +179,19 @@ class FedNLPrecondOptimizer:
         return self.compressor.aggregate(payloads, tuple(shape2),
                                          use_pallas=self.use_pallas)
 
+    def _sharded(self, fn, silo_specs):
+        """``fn`` under ``shard_map`` on a multi-device mesh (the silo
+        axis on ``data`` where ``silo_specs`` marks it, everything else
+        replicated); ``fn`` itself on one device."""
+        if self.mesh is None or self.mesh.size == 1:
+            return fn
+        spec = lambda silo: P("data") if silo else P()
+        ins, outs = silo_specs
+        return jax.shard_map(fn, mesh=self.mesh,
+                             in_specs=tuple(spec(s) for s in ins),
+                             out_specs=tuple(spec(s) for s in outs),
+                             check_vma=False)
+
     def _learn_tensor(self, h, d_obs):
         """One tensor's compressed Hessian learning: the payload-space
         increment s = C(D^k - H^k) (or the server mean of per-silo
@@ -183,20 +202,31 @@ class FedNLPrecondOptimizer:
             # cross-silo: per-silo payloads, ONE dense accumulator.
             # Each silo runs the fused diff kernel against the same
             # shared H — the per-silo dense diff never materializes.
-            obs2 = d_obs.astype(jnp.float32).reshape(
-                (d_obs.shape[0],) + h2.shape)
-            vals, idx, sq = jax.vmap(
-                lambda a: self._diff_payload(a, h2))(obs2)
-            s = self._payload_mean(vals, idx, h2.shape).reshape(h.shape)
+            n = d_obs.shape[0]
+            obs2 = d_obs.astype(jnp.float32).reshape((n,) + h2.shape)
+            silo = (self.mesh is not None
+                    and dict(self.mesh.shape).get("data") == n)
+            vals, idx, sq = self._sharded(
+                lambda o, hh: jax.vmap(
+                    lambda a: self._diff_payload(a, hh))(o),
+                ((silo, False), (silo, silo, silo)))(obs2, h2)
+            s = self._sharded(
+                lambda v, i: (self._payload_mean(v, i, h2.shape),),
+                ((False, False), (False,)))(vals, idx)[0].reshape(h.shape)
             # l^k = mean_i ||D_i - H||_F, scale-matched (Option 2)
             l = jnp.mean(jnp.sqrt(sq / h.size + 1e-30))
         else:
             # the uplink object is the payload; H learns from it.
             # Fused: D = obs - H is formed tile-wise inside the
             # payload kernel, and sq = ||D||_F^2 rides along.
-            vals, idx, sq = self._diff_payload(_as2d(d_obs), h2)
-            s = self._payload_mean(vals[None], idx[None],
-                                   h2.shape).reshape(h.shape)
+            def learn(o, hh):
+                vals, idx, sq = self._diff_payload(o, hh)
+                return self._payload_mean(vals[None], idx[None],
+                                          hh.shape), sq
+
+            s, sq = self._sharded(learn, ((False, False), (False, False)))(
+                _as2d(d_obs), h2)
+            s = s.reshape(h.shape)
             # l^k correction (Option 2), scale-matched to the diagonal
             l = jnp.sqrt(sq / h.size + 1e-30)
         return s, l

@@ -19,7 +19,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental import enable_x64
 
 from repro.core import FedNL, FedNLPP, TopK
 from repro.core.compressors import (
@@ -64,7 +63,7 @@ def test_every_registered_family_covered():
 def test_aggregate_matches_decompress_mean(family):
     """Acceptance: aggregate == mean of per-silo decompression, per
     registered family, at f64 tolerance (reduction order differs)."""
-    with enable_x64():
+    with jax.enable_x64(True):
         comp = make_compressor(family, _FAMILY_LEVELS[family])
         shape = _family_shape(family)
         _, payloads = _stacked_payloads(comp, shape)
@@ -89,7 +88,7 @@ def test_aggregate_fast_path_is_registered(family):
 def test_aggregate_under_vmap_over_seeds():
     """The engine vmaps whole steps over the seed axis; aggregate must
     batch transparently and match the per-seed serial results."""
-    with enable_x64():
+    with jax.enable_x64(True):
         comp = make_compressor("randk", 13)
         shape = (12, 12)
         stack = jax.random.normal(jax.random.PRNGKey(0),
@@ -120,10 +119,7 @@ def test_aggregate_under_shard_map_over_silos():
         jax.config.update("jax_enable_x64", True)
         from functools import partial
         from jax.sharding import PartitionSpec as P
-        try:
-            from jax import shard_map as shard_map
-        except ImportError:
-            from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from repro.core.compressors import SparsePayload, TopK
 
         comp = TopK(k=50)
@@ -134,7 +130,8 @@ def test_aggregate_under_shard_map_over_silos():
         payloads = jax.vmap(comp.compress)(stack, keys)
         serial = comp.aggregate(payloads, shape)
 
-        mesh = jax.make_mesh((4,), ("data",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((4,), ("data",))
 
         @partial(shard_map, mesh=mesh, in_specs=(P("data"), P("data")),
                  out_specs=P())
@@ -163,7 +160,7 @@ def test_aggregate_sparse_negative_padding_dropped():
     """-1 payload padding must vanish from the aggregate even when its
     value slot is nonzero (same regression class as decompress: jax
     normalizes negative indices ahead of mode='drop')."""
-    with enable_x64():
+    with jax.enable_x64(True):
         pay = SparsePayload(
             values=jnp.asarray([[1.0, 2.0, 3.0], [4.0, 5.0, 0.0]]),
             indices=jnp.asarray([[0, 5, -1], [5, -1, -1]], jnp.int32),
@@ -182,7 +179,7 @@ def test_aggregate_blocksparse_ties_and_padding():
     """BlockTopKThreshold payloads under a tie cluster spanning the k-th
     position carry -1 padding and exactly-k survivors (PR-2 semantics);
     the per-tile scatter-add aggregate must agree with the fallback."""
-    with enable_x64():
+    with jax.enable_x64(True):
         comp = BlockTopKThreshold(k_per_block=3, block=4)
         base = jnp.full((4, 4), 1.0).at[0, 0].set(1.0001)
         stack = jnp.stack([base, 2.0 * base, -base])
@@ -197,7 +194,7 @@ def test_aggregate_blocksparse_ties_and_padding():
 def test_aggregate_blocksparse_nonmultiple_shape_cropped():
     """Shapes that don't divide the block: padded tiles accumulate zeros
     and the aggregate crops back to the true shape."""
-    with enable_x64():
+    with jax.enable_x64(True):
         comp = make_compressor("blocktopk", 5)  # block=128 > shape
         shape = (10, 14)
         _, payloads = _stacked_payloads(comp, shape, seed=3)
@@ -214,7 +211,7 @@ def test_scale_payload_masked_mean(family):
     partial-participation masking used by FedNL-PP/PPBC, across wire
     formats (values / low-rank middle / dithering signs); the weighting
     is ``scale_payload`` applied inside the aggregate entry point."""
-    with enable_x64():
+    with jax.enable_x64(True):
         comp = make_compressor(family, _FAMILY_LEVELS[family])
         shape = _family_shape(family)
         _, payloads = _stacked_payloads(comp, shape, seed=4)
@@ -238,7 +235,7 @@ class _FallbackTopK(TopK):
 
 @pytest.fixture(scope="module")
 def problem():
-    with enable_x64():
+    with jax.enable_x64(True):
         data = make_synthetic(jax.random.PRNGKey(0), alpha=0.5, beta=0.5,
                               n=6, m=40, d=10, lam=1e-3)
         data = data._replace(a=data.a.astype(jnp.float64),
@@ -250,7 +247,7 @@ def problem():
 def test_fednl_run_fast_path_matches_fallback(problem):
     """Swapping the structure-aware aggregate for decompress-then-mean
     must not move serial .run trajectories beyond f64 noise."""
-    with enable_x64():
+    with jax.enable_x64(True):
         x0 = jnp.full((10,), 0.4, jnp.float64)
         runs = {}
         for tag, comp in [("fast", TopK(k=30)), ("slow", _FallbackTopK(k=30))]:
@@ -264,7 +261,7 @@ def test_fednl_run_fast_path_matches_fallback(problem):
 def test_fednl_pp_masked_fast_path_matches_fallback(problem):
     """FedNL-PP's masked server aggregate (zero-weighted inactive silos
     in payload space) equals the dense masked mean, end to end."""
-    with enable_x64():
+    with jax.enable_x64(True):
         x0 = jnp.full((10,), 0.4, jnp.float64)
         runs = {}
         for tag, comp in [("fast", TopK(k=30)), ("slow", _FallbackTopK(k=30))]:
@@ -287,7 +284,7 @@ def test_aggregate_topk_randk_exact_at_d4096_via_tiled_kernel():
     from repro.core.compressors import RandK
     from repro.kernels.scatter_accum import scatter_accumulate
 
-    with enable_x64():
+    with jax.enable_x64(True):
         d, n = 4096, 2
         stack = jax.random.normal(jax.random.PRNGKey(0), (n, d, d))
         keys = jax.random.split(jax.random.PRNGKey(1), n)
@@ -366,7 +363,7 @@ def test_fednl_precond_silo_axis_matches_per_silo_reference():
 
 
 def test_entropy_index_bits_below_raw_for_sparse():
-    with enable_x64():
+    with jax.enable_x64(True):
         comp = TopK(k=16)
         raw = payload_bits(comp, (32, 32))
         ent = payload_bits(comp, (32, 32), index_coding="entropy")
@@ -416,7 +413,7 @@ def test_sweep_records_carry_entropy_column(problem):
     carrying sparsifiers."""
     from repro.engine import ExperimentSpec, Sweep
 
-    with enable_x64():
+    with jax.enable_x64(True):
         spec = ExperimentSpec("fednl", "topk", 20,
                               params=dict(option=2), num_rounds=2)
         res = Sweep([spec]).run(
@@ -457,7 +454,7 @@ def test_fused_diff_payloads_matches_unfused_compress():
     from repro.core.compressors import BlockTopK
     from repro.core.linalg import frob_norm
 
-    with enable_x64():
+    with jax.enable_x64(True):
         comp = BlockTopK(k_per_block=9, block=8)
         kh, ko = jax.random.split(jax.random.PRNGKey(21))
         h_new = jax.random.normal(kh, (3, 16, 16), jnp.float64)
@@ -480,7 +477,7 @@ def test_fednl_fused_uplink_run_matches_unfused(problem):
     f64 noise — the fusion changes scheduling, not numerics."""
     from repro.core.compressors import BlockTopK
 
-    with enable_x64():
+    with jax.enable_x64(True):
         x0 = jnp.full((10,), 0.4, jnp.float64)
         comp = BlockTopK(k_per_block=9, block=8)
         runs = {}
@@ -504,7 +501,7 @@ def test_aggregate_streams_above_vmem_budget():
     from repro.kernels import VMEM_BUDGET_BYTES
     from repro.kernels.scatter_accum import scatter_accumulate
 
-    with enable_x64():
+    with jax.enable_x64(True):
         n, k, d = 700, 1024, 64
         pair = jnp.dtype(jnp.float64).itemsize + jnp.dtype(jnp.int32).itemsize
         assert n * k * pair > VMEM_BUDGET_BYTES  # the premise
@@ -538,7 +535,7 @@ def test_aggregate_weight_zero_silo_bit_exact():
     """A weight-0 silo contributes nothing, bit-exactly: zeroing silo
     j's weight gives the same aggregate as padding silo j's indices
     out of the payload entirely."""
-    with enable_x64():
+    with jax.enable_x64(True):
         comp = TopK(k=17)
         shape = (12, 12)
         _, pay = _stacked_payloads(comp, shape)
@@ -569,7 +566,8 @@ def test_sharded_scatter_accumulate_four_devices():
             sharded_scatter_accumulate)
         from repro.launch.sharding import accumulator_spec
 
-        mesh = jax.make_mesh((4,), ("data",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((4,), ("data",))
         shape = (16, 16)
         ks = jax.random.split(jax.random.PRNGKey(0), 2)
         vals = jax.random.normal(ks[0], (6, 20), dtype=jnp.float64)

@@ -124,7 +124,7 @@ def test_blocksq_intermediate_is_flagged():
 
 
 def test_f64_laundered_through_f32_is_flagged():
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         def bad(x):
             y = x.astype(jnp.float32)  # silent precision loss
             return (y * 2.0).astype(jnp.float64)  # laundered back
@@ -139,7 +139,7 @@ def test_selection_only_downcast_passes():
     """BlockTopKThreshold's documented pattern: f32 is fine for
     *selecting* indices (the taint dies at the bool/int boundary) as
     long as the selected values come from the f64 original."""
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         def ok(x):
             score = jnp.abs(x).astype(jnp.float32)
             _, idx = jax.lax.top_k(score, 3)

@@ -7,7 +7,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental import enable_x64
 
 from repro.core import CohortSpec, FedNLPP, TopK
 from repro.core.cohort import (
@@ -26,7 +25,7 @@ D, N = 10, 6
 
 @pytest.fixture(scope="module")
 def problem():
-    with enable_x64():
+    with jax.enable_x64(True):
         data = make_synthetic(jax.random.PRNGKey(0), alpha=0.5, beta=0.5,
                               n=N, m=30, d=D, lam=1e-3)
         data = data._replace(a=data.a.astype(jnp.float64),
@@ -107,7 +106,7 @@ def test_cohort_recovers_fednl_pp_bitwise(problem):
     """beta = 0 + deadline_quantile = 1 is FedNL-PP with tau = cohort:
     identical key usage, unit weights for the sampled cohort — the two
     trajectories must agree BITWISE round for round."""
-    with enable_x64():
+    with jax.enable_x64(True):
         comp = TopK(k=20)
         x0 = jnp.zeros(D, jnp.float64)
         pp = FedNLPP(problem["grad"], problem["hess"], comp, tau=2)
@@ -124,7 +123,7 @@ def test_cohort_straggler_discount_applied(problem):
     """With an aggressive deadline and beta > 0, sampled stragglers get
     exactly the (1 + staleness)^(-beta) weight and unsampled silos get
     0 — checked against the hand-computed arrival mask."""
-    with enable_x64():
+    with jax.enable_x64(True):
         spec = CohortSpec(cohort=4, staleness_beta=0.5,
                           deadline_quantile=0.5, seed=1)
         co = CohortFedNLPP(problem["grad"], problem["hess"], TopK(k=20),
@@ -153,7 +152,7 @@ def test_cohort_population_mismatch_raises(problem):
 
 
 def test_cohort_converges_and_is_deterministic(problem):
-    with enable_x64():
+    with jax.enable_x64(True):
         spec = CohortSpec(cohort=3, population=N)
         co = CohortFedNLPP(problem["grad"], problem["hess"], TopK(k=30),
                            cohort=spec, alpha=1.0)
@@ -176,7 +175,7 @@ def test_experiment_spec_cohort_through_sweep(problem):
     """ONE CohortSpec drives the whole cell: the method construction,
     the display label, and the traffic-model pricing (cohort link +
     cohort size, not the sweep-wide preset)."""
-    with enable_x64():
+    with jax.enable_x64(True):
         spec = ExperimentSpec("fednl-cohort", "topk", 20,
                               cohort=CohortSpec(cohort=3, population=N),
                               num_rounds=8)

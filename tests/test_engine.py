@@ -5,7 +5,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental import enable_x64
 
 from repro.core import FedNL, FedNLBC, FedNLCR, FedNLLS, FedNLPP, RankR, TopK
 from repro.core.objectives import batch_grad, batch_hess, global_value
@@ -24,7 +23,7 @@ D, N = 12, 8
 
 @pytest.fixture(scope="module")
 def problem():
-    with enable_x64():
+    with jax.enable_x64(True):
         data = make_synthetic(jax.random.PRNGKey(0), alpha=0.5, beta=0.5,
                               n=N, m=40, d=D, lam=1e-3)
         data = data._replace(a=data.a.astype(jnp.float64),
@@ -67,7 +66,7 @@ def _roundtrip_params(d):
 def test_registry_round_trip(problem):
     """Every registered method is constructible by name and survives a
     2-round run through the shared driver."""
-    with enable_x64():
+    with jax.enable_x64(True):
         params = _roundtrip_params(D)
         x0 = jnp.zeros(D, jnp.float64)
         comp = build_compressor("rankr", 1)
@@ -100,7 +99,7 @@ def test_vmapped_sweep_matches_serial_runs(problem):
     transient can amplify those through compressor tie-breaks. In the
     fig3 regime (start in the local basin) the measured worst case is
     ~3e-14; 1e-10 leaves margin while staying firmly float64."""
-    with enable_x64():
+    with jax.enable_x64(True):
         x0 = jnp.zeros(D, jnp.float64)
         seeds, rounds = (0, 1, 2), 8
         specs = [ExperimentSpec("fednl", "rankr", lvl,
@@ -123,7 +122,7 @@ def test_vmapped_sweep_matches_serial_runs(problem):
 def test_sweep_distinct_seeds_distinct_trajectories(problem):
     """Randomized compressors must actually fold the seed in — identical
     trajectories across seeds would mean the vmap axis is dead."""
-    with enable_x64():
+    with jax.enable_x64(True):
         x0 = jnp.full((D,), 0.5, jnp.float64)
         spec = ExperimentSpec("fednl", "randk", 40,
                               params=dict(option=2, alpha=0.5),
@@ -133,7 +132,7 @@ def test_sweep_distinct_seeds_distinct_trajectories(problem):
 
 
 def test_sweep_records_and_summary(problem):
-    with enable_x64():
+    with jax.enable_x64(True):
         spec = ExperimentSpec("fednl", "rankr", 1,
                               params=dict(option=1, mu=1e-3),
                               seeds=(0, 1), num_rounds=3, name="cellA")
@@ -150,7 +149,7 @@ def test_sweep_records_and_summary(problem):
 def test_engine_bc_records_learned_model(problem):
     """FedNL-BC's monitored trajectory is z (the learned model devices
     actually hold), not the server's uncompressed x."""
-    with enable_x64():
+    with jax.enable_x64(True):
         spec = ExperimentSpec("fednl-bc", "topk", D * D,
                               params=dict(model_compressor=("topk", D),
                                           p=1.0, option=1, mu=1e-3),
@@ -163,11 +162,12 @@ def test_engine_bc_records_learned_model(problem):
 def test_sharded_sweep_matches_plain_single_device(problem):
     """The mesh path (core/federated.py shard_map) agrees with the vmap
     path on a trivial 1-device mesh."""
-    with enable_x64():
+    with jax.enable_x64(True):
         x0 = jnp.full((D,), 0.3, jnp.float64)
         spec = ExperimentSpec("fednl", "rankr", 1, params=dict(option=2),
                               seeds=(0,), num_rounds=4)
-        mesh = jax.make_mesh((1,), ("data",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((1,), ("data",))
         plain = Sweep([spec]).run(problem, x0=x0).cells[0]
         sharded = Sweep([spec], mesh=mesh).run(problem, x0=x0).cells[0]
         np.testing.assert_allclose(sharded.xs, plain.xs, rtol=0, atol=1e-10)
@@ -200,7 +200,7 @@ def test_bits_accounting_identical_pre_post_refactor(problem):
 
 
 def test_engine_bits_curve_matches_method_accounting(problem):
-    with enable_x64():
+    with jax.enable_x64(True):
         spec = ExperimentSpec("fednl", "rankr", 1,
                               params=dict(option=1, mu=1e-3),
                               seeds=(0,), num_rounds=3)
@@ -214,7 +214,7 @@ def test_engine_measured_bits_match_analytic_under_x64(problem):
     """Acceptance: a Sweep cell reports measured per-round bits (derived
     from the payload structure) that match the analytic bits_per_round
     under x64, for the four acceptance compressor families."""
-    with enable_x64():
+    with jax.enable_x64(True):
         x0 = jnp.zeros(D, jnp.float64)
         specs = [
             ExperimentSpec("fednl", "rankr", 2,
@@ -239,7 +239,7 @@ def test_engine_measured_bits_match_analytic_under_x64(problem):
 def test_engine_measured_bits_bc_uplink_downlink(problem):
     """FedNL-BC's measured accounting covers both directions: the uplink
     Hessian payload and the downlink model payload."""
-    with enable_x64():
+    with jax.enable_x64(True):
         from repro.core import TopK
         from repro.engine import measured_bits_per_round
 

@@ -8,7 +8,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental import enable_x64
 
 from repro.core import (
     FedNL,
@@ -35,7 +34,7 @@ pytestmark = pytest.mark.slow
 
 @pytest.fixture(scope="module")
 def problem():
-    with enable_x64():
+    with jax.enable_x64(True):
         data = make_synthetic(jax.random.PRNGKey(0), alpha=0.5, beta=0.5,
                               n=8, m=60, d=16, lam=1e-3)
         data = data._replace(a=data.a.astype(jnp.float64),
@@ -55,7 +54,7 @@ def _x0_near(problem, scale=1e-2, seed=3):
 
 def test_fednl_linear_rate_eq6(problem):
     """(6): ||x^k - x*||^2 <= (1/2^k) ||x^0 - x*||^2 locally."""
-    with enable_x64():
+    with jax.enable_x64(True):
         x0 = _x0_near(problem)
         alg = FedNL(problem["grad"], problem["hess"], RankR(1), alpha=1.0,
                     option=1, mu=1e-3)
@@ -67,7 +66,7 @@ def test_fednl_linear_rate_eq6(problem):
 
 def test_fednl_superlinear_ratio_decreases(problem):
     """(8): r_{k+1}/r_k -> 0."""
-    with enable_x64():
+    with jax.enable_x64(True):
         x0 = _x0_near(problem, scale=5e-2)
         alg = FedNL(problem["grad"], problem["hess"], RankR(2), alpha=1.0,
                     option=1, mu=1e-3)
@@ -79,7 +78,7 @@ def test_fednl_superlinear_ratio_decreases(problem):
 
 def test_fednl_hessian_learning(problem):
     """Phi^k linear decay (7): H_i^k -> hess_i(x*)."""
-    with enable_x64():
+    with jax.enable_x64(True):
         x0 = _x0_near(problem)
         alg = FedNL(problem["grad"], problem["hess"], TopK(k=64), alpha=1.0,
                     option=2)
@@ -95,7 +94,7 @@ def test_fednl_hessian_learning(problem):
 
 
 def test_fednl_option2_converges(problem):
-    with enable_x64():
+    with jax.enable_x64(True):
         x0 = _x0_near(problem)
         alg = FedNL(problem["grad"], problem["hess"], RankR(1), alpha=1.0,
                     option=2)
@@ -105,7 +104,7 @@ def test_fednl_option2_converges(problem):
 
 
 def test_fednl_unbiased_randk(problem):
-    with enable_x64():
+    with jax.enable_x64(True):
         x0 = _x0_near(problem)
         comp = RandK(k=64)
         omega = comp.spec((16, 16)).omega
@@ -117,7 +116,7 @@ def test_fednl_unbiased_randk(problem):
 
 
 def test_n0_linear_ns_quadratic(problem):
-    with enable_x64():
+    with jax.enable_x64(True):
         x0 = _x0_near(problem, scale=5e-2)
         grad_fn = problem["grad"]
         h0 = jnp.mean(problem["hess"](x0), axis=0)
@@ -136,7 +135,7 @@ def test_n0_linear_ns_quadratic(problem):
 
 
 def test_fednl_pp_converges(problem):
-    with enable_x64():
+    with jax.enable_x64(True):
         x0 = _x0_near(problem)
         alg = FedNLPP(problem["grad"], problem["hess"], RankR(1), tau=3)
         final, _ = alg.run(x0, 8, 60)
@@ -145,7 +144,7 @@ def test_fednl_pp_converges(problem):
 
 
 def test_fednl_ls_global(problem):
-    with enable_x64():
+    with jax.enable_x64(True):
         x_far = jnp.full((16,), 3.0, jnp.float64)
         alg = FedNLLS(problem["val"], problem["grad"], problem["hess"],
                       RankR(1), mu=1e-3)
@@ -157,7 +156,7 @@ def test_fednl_ls_global(problem):
 
 
 def test_fednl_cr_global(problem):
-    with enable_x64():
+    with jax.enable_x64(True):
         x_far = jnp.full((16,), 2.0, jnp.float64)
         alg = FedNLCR(problem["grad"], problem["hess"], RankR(1),
                       l_star=problem["consts"]["L_star"])
@@ -170,7 +169,7 @@ def test_fednl_cr_global(problem):
 
 
 def test_fednl_bc_converges(problem):
-    with enable_x64():
+    with jax.enable_x64(True):
         x0 = _x0_near(problem)
         d = 16
         alg = FedNLBC(problem["grad"], problem["hess"],
@@ -183,7 +182,7 @@ def test_fednl_bc_converges(problem):
 
 def test_newton_triangle_specializations(problem):
     """FedNL with C=0, alpha=0, H_i^0 = hess_i(x0) IS Newton-Zero."""
-    with enable_x64():
+    with jax.enable_x64(True):
         x0 = _x0_near(problem)
         alg = FedNL(problem["grad"], problem["hess"], Zero(), alpha=0.0,
                     option=1, mu=1e-3)

@@ -254,7 +254,8 @@ def test_fednl_sharded_matches_vmap_single_device():
     alg_plain = FedNL(grad_fn, hess_fn, RankR(1), option=2)
     _, xs_plain = alg_plain.run(x0, 4, 6)
 
-    mesh = jax.make_mesh((1,), ("data",))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((1,), ("data",))
     _, xs_sh = run_fednl_sharded(data, RankR(1), mesh, x0, 6, option=2)
     np.testing.assert_allclose(np.asarray(xs_plain), np.asarray(xs_sh),
                                atol=2e-4)  # reduction-order noise in f32
@@ -278,7 +279,8 @@ def test_fednl_sharded_multidevice_subprocess():
         x0 = jnp.ones(10) * 0.3
         alg = FedNL(grad_fn, hess_fn, RankR(1), option=2)
         _, xs_plain = alg.run(x0, 8, 6)
-        mesh = jax.make_mesh((4,), ("data",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((4,), ("data",))
         _, xs_sh = run_fednl_sharded(data, RankR(1), mesh, x0, 6, option=2)
         np.testing.assert_allclose(np.asarray(xs_plain), np.asarray(xs_sh),
                                    atol=1e-4)
@@ -290,3 +292,53 @@ def test_fednl_sharded_multidevice_subprocess():
     out = subprocess.run([sys.executable, "-c", code], env=env,
                          capture_output=True, text=True, timeout=600)
     assert "SHARDED_OK" in out.stdout, out.stdout + out.stderr
+
+
+def test_mesh_paths_refuse_what_cannot_shard_subprocess():
+    """On 4 forced host devices, a sweep cell whose silo count does not
+    divide the mesh, a cell that has no sharded path, and a training
+    batch that does not divide the data axis all raise instead of
+    running on one device."""
+    code = textwrap.dedent("""
+        import os
+        os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+        import jax, jax.numpy as jnp
+        from repro.core.objectives import batch_grad, batch_hess, global_value
+        from repro.data.synthetic import make_synthetic
+        from repro.engine import ExperimentSpec, Sweep
+        from repro.launch.mesh import make_mesh
+        from repro.launch.train import train
+
+        data = make_synthetic(jax.random.PRNGKey(0), 0.5, 0.5, n=6, m=20,
+                              d=8)
+        prob = dict(grad=lambda x: batch_grad(x, data),
+                    hess=lambda x: batch_hess(x, data),
+                    val=lambda x: global_value(x, data), n=6, d=8,
+                    data=data)
+        mesh = make_mesh((4,), ("data",))
+        for spec, why in [
+                (ExperimentSpec("fednl", "topk", 8, num_rounds=2),
+                 "do not divide"),
+                (ExperimentSpec("fednl-pp", "topk", 8, num_rounds=2,
+                                params=dict(tau=2)), "only 'fednl'")]:
+            try:
+                Sweep([spec], mesh=mesh).run(prob, x0=jnp.zeros(8))
+            except ValueError as e:
+                assert why in str(e), e
+            else:
+                raise AssertionError(f"{spec.label} ran on the mesh")
+        try:
+            train("qwen2-0.5b", smoke=True, steps=1, batch=6, seq=16,
+                  optimizer="fednl", curvature_k=64)
+        except ValueError as e:
+            assert "does not divide" in str(e), e
+        else:
+            raise AssertionError("batch 6 trained on a 4-way data axis")
+        print("REFUSED_OK")
+    """)
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
+    env.pop("XLA_FLAGS", None)
+    out = subprocess.run([sys.executable, "-c", code], env=env,
+                         capture_output=True, text=True, timeout=600)
+    assert "REFUSED_OK" in out.stdout, out.stdout + out.stderr

@@ -455,11 +455,10 @@ def test_diff_topk_payload_fused_matches_unfused_f64(use_pallas):
     unfused ``block_topk_payload(a - b)`` on the same backend, and its
     sumsq equals ``sum((a - b)**2)``. Zero accuracy change is the
     acceptance bar for the fusion."""
-    from jax.experimental import enable_x64
 
     from repro.kernels.block_topk import diff_topk_payload
 
-    with enable_x64():
+    with jax.enable_x64(True):
         ka, kb = jax.random.split(jax.random.PRNGKey(11))
         a = jax.random.normal(ka, (256, 256), jnp.float64)
         b = jax.random.normal(kb, (256, 256), jnp.float64)
@@ -518,9 +517,8 @@ def test_scatter_accum_symmetric_fused_matches_two_pass_f64(tile):
     (c, r)) equals the two-pass oracle ``c + c.T - diag(diag(c))`` at
     f64 — on both the single-block and tiled kernels, with -1 payload
     padding present."""
-    from jax.experimental import enable_x64
 
-    with enable_x64():
+    with jax.enable_x64(True):
         d = 64
         ks = jax.random.split(jax.random.PRNGKey(13), 3)
         r = jax.random.randint(ks[0], (3, 40), 0, d)

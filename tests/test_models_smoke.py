@@ -82,14 +82,24 @@ NON_MOE = [a for a in ARCHS if get_config(a, smoke=True).moe is None
 def test_smoke_fednl_five_steps_decreasing(arch):
     """5 real fednl steps through the LAUNCH DRIVER (sharded params +
     opt state, curvature refresh every 2 steps, preconditioned updates)
-    on every arch in the zoo: finite, decreasing loss."""
-    from repro.launch.train import train
+    on every arch in the zoo: finite, decreasing loss. Each step draws a
+    fresh batch, so the decrease is read on one batch: the first
+    batch's loss after the 5 steps is below its loss before them."""
+    from repro.data.tokens import TokenPipeline
+    from repro.launch.train import add_modality_inputs, train
 
-    hist = train(arch, smoke=True, steps=5, batch=4, seq=32, lr=1e-3,
-                 optimizer="fednl", log_every=10, refresh_every=2,
-                 curvature_k=256)
+    run = train(arch, smoke=True, steps=5, batch=4, seq=32, lr=1e-3,
+                optimizer="fednl", log_every=10, refresh_every=2,
+                curvature_k=256)
+    hist = run.losses
     assert len(hist) == 5 and all(np.isfinite(h) for h in hist), hist
-    assert hist[-1] < hist[0], hist
+    cfg = get_config(arch, smoke=True)
+    first = add_modality_inputs(TokenPipeline(
+        vocab_size=cfg.vocab, seq_len=32, global_batch=4, seed=0).batch(0),
+        cfg, 0)
+    after = float(jax.jit(build_model(cfg, use_remat=True).loss_fn)(
+        run.params, first))
+    assert after < hist[0], (after, hist)
 
 
 @pytest.mark.parametrize("arch", NON_MOE)

@@ -9,7 +9,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental import enable_x64
 
 from _dense_refs import (
     blocktopk_dense_ref,
@@ -60,7 +59,7 @@ def test_blocktopk_bits_clamped_to_block_size():
 
 def test_bits_match_payload_shapes_after_clamp():
     # the analytic claim equals the measured payload structure under x64
-    with enable_x64():
+    with jax.enable_x64(True):
         for comp, shape in [(TopK(k=100), (3, 3)),
                             (TopK(k=100, symmetric=True), (4, 4)),
                             (RandK(k=100), (3, 3)),

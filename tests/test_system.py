@@ -17,7 +17,7 @@ def test_train_driver_learns():
     from repro.launch.train import train
 
     hist = train("qwen2-0.5b", smoke=True, steps=30, batch=4, seq=64,
-                 lr=1e-3, optimizer="adamw", log_every=100)
+                 lr=1e-3, optimizer="adamw", log_every=100).losses
     assert hist[-1] < hist[0] - 0.5, hist[:3] + hist[-3:]
 
 
@@ -26,7 +26,7 @@ def test_train_driver_fednl_optimizer_learns():
     from repro.launch.train import train
 
     hist = train("qwen2-0.5b", smoke=True, steps=30, batch=4, seq=64,
-                 lr=2e-3, optimizer="fednl", log_every=100)
+                 lr=2e-3, optimizer="fednl", log_every=100).losses
     assert hist[-1] < hist[0] - 0.5, hist[:3] + hist[-3:]
 
 
@@ -69,7 +69,8 @@ def test_dryrun_smoke_mesh_subprocess():
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax
         from repro.launch.dryrun import dryrun_pair
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 4), ("data", "model"))
         row = dryrun_pair("qwen2-0.5b", "train_4k", mesh=mesh, smoke=True,
                           verbose=False, with_probes=False)
         assert row["status"] == "ok", row

@@ -9,7 +9,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental import enable_x64
 
 from repro.core import (
     BlockTopK,
@@ -98,7 +97,7 @@ def test_roundtrip_fp32_bit_exact(name):
 
 @pytest.mark.parametrize("name", sorted(_families()))
 def test_roundtrip_f64_bit_exact(name):
-    with enable_x64():
+    with jax.enable_x64(True):
         comp = _families()[name]
         p = comp.compress(_m(jnp.float64), jax.random.PRNGKey(1))
         dec = decode(encode(p))
@@ -342,7 +341,7 @@ def test_sweep_records_seconds_per_round():
     from repro.data.synthetic import make_synthetic
     from repro.engine import ExperimentSpec, Sweep
 
-    with enable_x64():
+    with jax.enable_x64(True):
         data = make_synthetic(jax.random.PRNGKey(0), alpha=0.5, beta=0.5,
                               n=4, m=24, d=8, lam=1e-3)
         problem = dict(grad=lambda x: batch_grad(x, data),
