@@ -1,0 +1,8 @@
+"""block_scatter_roofline.train: the least time of the block_scatter kernel's work
+(chipbench/counts/kernels.py) over its device time in the trace."""
+
+from chipbench.layer import roofline
+
+
+def read(ctx):
+    return roofline(ctx, "block_scatter")
