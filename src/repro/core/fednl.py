@@ -108,8 +108,9 @@ class FedNL(MethodBase):
             sub = jax.random.fold_in(sub, jax.lax.axis_index(self.axis_name))
         silo_keys = jax.random.split(sub, n)
 
-        grads = self.grad_fn(state.x)                     # (n, d)
-        hesses = self.hess_fn(state.x)                    # (n, d, d)
+        with jax.named_scope("fednl.oracle"):
+            grads = self.grad_fn(state.x)                 # (n, d)
+            hesses = self.hess_fn(state.x)                # (n, d, d)
 
         # devices uplink payloads of D_i = hess_i - H_i (fused
         # diff->select->payload where the compressor supports it, so the
@@ -118,21 +119,23 @@ class FedNL(MethodBase):
         # the (n, d, d) decompressed stack never reaches the server
         payloads, l_i = self._uplink_diff_payloads(hesses, state.h_local,
                                                    silo_keys)
-        s_i = self._local_hessians(payloads, hesses.shape[1:])
+        with jax.named_scope("fednl.local_update"):
+            s_i = self._local_hessians(payloads, hesses.shape[1:])
+            h_local = state.h_local + self.alpha * s_i
 
-        grad = self._mean(grads)
-        s_mean = self._server_aggregate(payloads, hesses.shape[1:])
-        l_mean = self._mean(l_i)
-
-        h_global = state.h_global + self.alpha * s_mean
-        h_local = state.h_local + self.alpha * s_i
+        with jax.named_scope("fednl.server"):
+            grad = self._mean(grads)
+            s_mean = self._server_aggregate(payloads, hesses.shape[1:])
+            l_mean = self._mean(l_i)
+            h_global = state.h_global + self.alpha * s_mean
 
         # Model update uses the *current* H^k (paper lines 11-12 use H^k).
-        if self.option == 1:
-            h_eff = project_psd(state.h_global, self.mu)
-        else:
-            d = state.x.shape[0]
-            h_eff = state.h_global + l_mean * jnp.eye(d, dtype=state.x.dtype)
+        with jax.named_scope("fednl.solve"):
+            if self.option == 1:
+                h_eff = project_psd(state.h_global, self.mu)
+            else:
+                d = state.x.shape[0]
+                h_eff = state.h_global + l_mean * jnp.eye(d, dtype=state.x.dtype)
         x_new = state.x - solve_newton_system(h_eff, grad)
 
         return FedNLState(x_new, h_local, h_global, key, state.step + 1)

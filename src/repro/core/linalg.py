@@ -32,7 +32,8 @@ def solve_newton_system(h: jax.Array, g: jax.Array) -> jax.Array:
     """Solve H x = g for symmetric (assumed PD) H via Cholesky with an
     LU fallback baked in numerically (jnp.linalg.solve is LAPACK gesv on
     CPU and a triangular solve pipeline on TPU)."""
-    return jnp.linalg.solve(h, g)
+    with jax.named_scope("fednl.solve"):
+        return jnp.linalg.solve(h, g)
 
 
 def solve_cubic_subproblem(

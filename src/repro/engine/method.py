@@ -93,7 +93,8 @@ class MethodBase:
         """Device side: each silo compresses its own (d, d) Hessian
         diff into the wire payload it uplinks (vmapped over the silo
         axis; payload shapes are static)."""
-        return jax.vmap(self.comp.compress)(diff, silo_keys)
+        with jax.named_scope("fednl.uplink"):
+            return jax.vmap(self.comp.compress)(diff, silo_keys)
 
     def _uplink_diff_payloads(self, h_new, h_old, silo_keys):
         """Device side, fused: payloads of D_i = h_new_i - h_old_i plus
@@ -105,13 +106,14 @@ class MethodBase:
         that don't need the norms leave them dead (XLA DCE removes the
         reduction)."""
         fused = getattr(self.comp, "fused_diff_payloads", None)
-        if fused is not None:
-            return fused(h_new, h_old)
-        from ..core.linalg import frob_norm
+        with jax.named_scope("fednl.uplink"):
+            if fused is not None:
+                return fused(h_new, h_old)
+            from ..core.linalg import frob_norm
 
-        diff = h_new - h_old
-        return (jax.vmap(self.comp.compress)(diff, silo_keys),
-                jax.vmap(frob_norm)(diff))
+            diff = h_new - h_old
+            return (jax.vmap(self.comp.compress)(diff, silo_keys),
+                    jax.vmap(frob_norm)(diff))
 
     def _local_hessians(self, payloads, shape):
         """Device side: each silo reconstructs its OWN dense S_i from
@@ -128,11 +130,12 @@ class MethodBase:
         ``aggregate`` — the one weighting point for every wire format.
         Under shard_map (``axis_name`` set) the cross-silo reduction
         happens HERE, on the dense accumulator: one pmean of (d, d)."""
-        s = self.comp.aggregate(payloads, shape, weights=weights)
-        axis = getattr(self, "axis_name", None)
-        if axis is not None:
-            s = jax.lax.pmean(s, axis)
-        return s
+        with jax.named_scope("fednl.server"):
+            s = self.comp.aggregate(payloads, shape, weights=weights)
+            axis = getattr(self, "axis_name", None)
+            if axis is not None:
+                s = jax.lax.pmean(s, axis)
+            return s
 
     def measured_bits_per_round(self, d: int, index_coding: str = "raw"):
         """MEASURED per-round wire bits: the compressor's actual payload

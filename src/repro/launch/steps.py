@@ -95,33 +95,35 @@ def make_train_step(model: Model, optimizer, microbatches: int = 1,
                 obs = optimizer.observe(g_i)
             return carry, obs
 
-        _, obs = jax.lax.scan(silo_obs, 0,
-                              (sb, jnp.arange(n_silos, dtype=jnp.int32)))
+        with jax.named_scope("train.observe"):
+            _, obs = jax.lax.scan(silo_obs, 0,
+                                  (sb, jnp.arange(n_silos, dtype=jnp.int32)))
         return optimizer.refresh(state, obs)
 
     def train_step(params, opt_state, batch):
-        if microbatches == 1:
-            loss, grads = grads_of(params, batch)
-        else:
-            mb = jax.tree.map(
-                lambda x: x.reshape((microbatches, x.shape[0] // microbatches)
-                                    + x.shape[1:]), batch)
+        with jax.named_scope("train.forward_backward"):
+            if microbatches == 1:
+                loss, grads = grads_of(params, batch)
+            else:
+                mb = jax.tree.map(
+                    lambda x: x.reshape((microbatches, x.shape[0] // microbatches)
+                                        + x.shape[1:]), batch)
 
-            def acc_body(carry, mb_batch):
-                loss_acc, g_acc = carry
-                loss_i, g_i = grads_of(params, mb_batch)
-                g_acc = jax.tree.map(
-                    lambda a, g: a + g.astype(jnp.float32), g_acc, g_i)
-                return (loss_acc + loss_i, g_acc), None
+                def acc_body(carry, mb_batch):
+                    loss_acc, g_acc = carry
+                    loss_i, g_i = grads_of(params, mb_batch)
+                    g_acc = jax.tree.map(
+                        lambda a, g: a + g.astype(jnp.float32), g_acc, g_i)
+                    return (loss_acc + loss_i, g_acc), None
 
-            g0 = jax.tree.map(
-                lambda p: jnp.zeros(p.shape, jnp.float32), params)
-            (loss, grads), _ = jax.lax.scan(
-                acc_body, (jnp.zeros((), jnp.float32), g0), mb,
-                unroll=microbatches if unroll_microbatches else 1)
-            loss = loss / microbatches
-            grads = jax.tree.map(
-                lambda g, p: (g / microbatches).astype(p.dtype), grads, params)
+                g0 = jax.tree.map(
+                    lambda p: jnp.zeros(p.shape, jnp.float32), params)
+                (loss, grads), _ = jax.lax.scan(
+                    acc_body, (jnp.zeros((), jnp.float32), g0), mb,
+                    unroll=microbatches if unroll_microbatches else 1)
+                loss = loss / microbatches
+                grads = jax.tree.map(
+                    lambda g, p: (g / microbatches).astype(p.dtype), grads, params)
 
         refreshed = jnp.zeros((), jnp.float32)
         if second_order:
@@ -135,16 +137,20 @@ def make_train_step(model: Model, optimizer, microbatches: int = 1,
                 lambda s: observe_and_refresh(s, params, batch),
                 lambda s: s, opt_state)
             refreshed = do_refresh.astype(jnp.float32)
-            updates, opt_state = optimizer.precondition(
-                grads, opt_state, params)
-        else:
-            updates, opt_state = optimizer.update(grads, opt_state, params)
-        params = apply_updates(params, updates)
-        # NB: reduce per-leaf WITHOUT reshaping — flattening a 2D-sharded
-        # tensor forces GSPMD to all-gather it (412 GB for grok-1's
-        # stacked expert grads); jnp.sum over all axes partitions cleanly.
-        gnorm = jnp.sqrt(sum(jnp.sum(jnp.square(g.astype(jnp.float32)))
-                             for g in jax.tree.leaves(grads)))
+        with jax.named_scope("train.update"):
+            if second_order:
+                updates, opt_state = optimizer.precondition(
+                    grads, opt_state, params)
+            else:
+                updates, opt_state = optimizer.update(grads, opt_state,
+                                                      params)
+            params = apply_updates(params, updates)
+            # NB: reduce per-leaf WITHOUT reshaping — flattening a
+            # 2D-sharded tensor forces GSPMD to all-gather it (412 GB for
+            # grok-1's stacked expert grads); jnp.sum over all axes
+            # partitions cleanly.
+            gnorm = jnp.sqrt(sum(jnp.sum(jnp.square(g.astype(jnp.float32)))
+                                 for g in jax.tree.leaves(grads)))
         return params, opt_state, {"loss": loss, "grad_norm": gnorm,
                                    "curv_refreshed": refreshed}
 

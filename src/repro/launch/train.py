@@ -106,13 +106,21 @@ def train(arch: str, smoke: bool = True, steps: int = 20, batch: int = 8,
                          global_batch=batch, seed=seed)
     history = []
     refreshes = 0
+    unread = []  # (loss, curv_refreshed) still on the device
     t0 = time.time()
     for i in range(steps):
-        b = add_modality_inputs(pipe.batch(i), cfg, i)
-        params, opt_state, metrics = step_fn(params, opt_state, b)
-        history.append(float(metrics["loss"]))
-        refreshes += int(metrics.get("curv_refreshed", 0.0))
+        with jax.profiler.TraceAnnotation("train.batch"):
+            b = add_modality_inputs(pipe.batch(i), cfg, i)
+        with jax.profiler.TraceAnnotation("train.dispatch"):
+            params, opt_state, metrics = step_fn(params, opt_state, b)
+        unread.append((metrics["loss"], metrics["curv_refreshed"]))
         if i % log_every == 0 or i == steps - 1:
+            # the host waits for the device only here, so steps between
+            # log lines queue back to back
+            for loss, refreshed in jax.device_get(unread):
+                history.append(float(loss))
+                refreshes += int(refreshed)
+            unread.clear()
             extra = (f" curv_bits {curv_bits * refreshes}"
                      if curv_bits else "")
             print(f"step {i:5d} loss {history[-1]:.4f} "

@@ -166,8 +166,9 @@ class FedNLPrecondOptimizer:
         Frobenius sum-of-squares of D, one pass: on the Pallas path the
         dense (d, d) difference lives only in VMEM tiles — it never
         round-trips HBM — and ||D||_F comes free from the same tiles."""
-        return diff_topk_payload(a2d, b2d, k=self._k(), block=self.block,
-                                 use_pallas=self.use_pallas)
+        with jax.named_scope("fednl.uplink"):
+            return diff_topk_payload(a2d, b2d, k=self._k(), block=self.block,
+                                     use_pallas=self.use_pallas)
 
     def _payload_mean(self, vals: jax.Array, idx: jax.Array, shape2):
         """Dense mean of n stacked per-silo payloads through the one
@@ -176,8 +177,9 @@ class FedNLPrecondOptimizer:
         dense decompression, ONE accumulator."""
         payloads = BlockSparsePayload(values=vals, indices=idx,
                                       universe=self.block * self.block)
-        return self.compressor.aggregate(payloads, tuple(shape2),
-                                         use_pallas=self.use_pallas)
+        with jax.named_scope("fednl.server"):
+            return self.compressor.aggregate(payloads, tuple(shape2),
+                                             use_pallas=self.use_pallas)
 
     def _sharded(self, fn, silo_specs):
         """``fn`` under ``shard_map`` on a multi-device mesh (the silo
@@ -256,7 +258,9 @@ class FedNLPrecondOptimizer:
         steps and ``precondition`` every step."""
         out = jax.tree.map(self._learn_tensor, state.h, observations)
         s, l = self._pick(out, 0), self._pick(out, 1)
-        h_new = jax.tree.map(lambda h, si: h + self.alpha * si, state.h, s)
+        with jax.named_scope("fednl.server"):
+            h_new = jax.tree.map(lambda h, si: h + self.alpha * si, state.h,
+                                 s)
         return state._replace(h=h_new, l=l)
 
     def precondition(self, grads, state: FedNLPrecondState, params):
