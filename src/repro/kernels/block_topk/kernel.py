@@ -11,10 +11,16 @@ output format). ``block_topk_payload_kernel`` emits the WIRE FORMAT
 directly — per tile, k (value, in-tile flat index) pairs in flat order —
 so the compressed uplink never materializes a dense (d, d) buffer. The
 survivor compaction is scatter/sort-free: flat-order positions come from
-triangular-matmul cumsums, and the payload slots are gathered one tile
-row at a time with a two-level one-hot contraction (slot = 128 * hi +
-lo, so a row costs a (kp/128, b) and a (128, b) one-hot instead of a
-(b*b, k) one — bounded VMEM at any k); empty slots carry index -1.
+triangular-matmul cumsums, and the payload slots are filled 8 tile rows
+at a time with a two-level one-hot contraction (slot = 128 * hi + lo, so
+a row costs a (kp/128, b) and a (128, b) one-hot instead of a (b*b, k)
+one — bounded VMEM at any k); empty slots carry index -1. Every
+contraction is a single bf16 MXU pass accumulated in f32 whose operands
+are exact in bf16 (0/1 masks, counts <= b, one-hots times bf16 pieces of
+the value and the entry's row and column), so the payload is exact:
+f32 tiles split each value into three bf16 pieces, bf16 tiles take it
+whole; f64 tiles (interpret mode only) keep an f64 contraction. Tiles
+are at most 256 wide, where counts and coordinates stay exact in bf16.
 Payload rows leave the kernel as (kp/128, 128) blocks per tile, kp = k
 rounded up to a lane multiple, and the wrappers crop them to k.
 
@@ -101,8 +107,101 @@ def _iota(shape, dim):
         jnp.float32)
 
 
-def _dot(a, b, acc=jnp.float32):
-    return jnp.dot(a, b, precision=_HIGHEST, preferred_element_type=acc)
+def _mxu_bf16(a, b, dims):
+    """One bf16 MXU pass accumulated in f32: exact wherever every operand
+    is exact in bf16 and every output sums one nonzero product (or
+    integers below 2**24). The precision is explicit: under a caller's
+    ``default_matmul_precision("highest")`` Mosaic would be asked for fp32
+    passes, which it refuses on bf16 operands."""
+    return jax.lax.dot_general(a.astype(jnp.bfloat16),
+                               b.astype(jnp.bfloat16), dims,
+                               precision=jax.lax.Precision.DEFAULT,
+                               preferred_element_type=jnp.float32)
+
+
+def _flat_positions(mask: jax.Array) -> jax.Array:
+    """Flat-order exclusive position of each True entry, scatter/sort-
+    free: the within-row inclusive cumsum and the row offsets are
+    triangular matmuls (MXU work, no 1D scans), every operand a full
+    (b, b) tile of 0/1 or row counts <= b (exact in bf16 for b <= 256).
+    mask is (b0, b1) f32."""
+    b0, b1 = mask.shape
+    mm = (((1,), (0,)), ((), ()))
+    col = _iota((b1, b1), 0)
+    upper = (col <= _iota((b1, b1), 1)).astype(jnp.float32)
+    incl = _mxu_bf16(mask, upper, mm)                     # (b0, b1)
+    row_count = _mxu_bf16(mask, jnp.ones((b1, b1), jnp.float32), mm)
+    row = _iota((b0, b0), 0)
+    strict_lower = (_iota((b0, b0), 1) < row).astype(jnp.float32)
+    row_offset = _mxu_bf16(strict_lower, row_count, mm)  # (b0, b1), per row
+    return row_offset + incl - mask                       # (b0, b1)
+
+
+def _bf16_pieces(x: jax.Array, n: int) -> list:
+    """x (f32) as n terms, each x's residual with its low 16 bits cut, so
+    exact in bf16; for n = 3 the last residual has at most 8 significant
+    bits, and x_0 + (x_1 + x_2) == x exactly (normal f32 x)."""
+    pieces = []
+    for _ in range(n - 1):
+        bits = jax.lax.bitcast_convert_type(x, jnp.uint32)
+        top = jax.lax.bitcast_convert_type(bits & jnp.uint32(0xFFFF0000),
+                                           jnp.float32)
+        pieces.append(top)
+        x = x - top
+    return pieces + [x]
+
+
+def _compact_bf16(x_rows, pos_ref, mask_ref, vals_ref, idx_ref, n_sel,
+                  dtype, b0: int, b1: int):
+    """Compaction of f32 and bf16 tiles: one single-pass bf16 contraction
+    per 8-row group. Slot s = 128 * hi + lo; entry e fills (hi_e, lo_e).
+    The stationary operand is the lo one-hot lo[l, e] (the MXU latches it
+    transposed), the streamed one the hi one-hot times a stack of rows
+    exact in bf16: the value's pieces (3 for f32, 1 for bf16), the tile
+    row and the tile column of each entry. The group's 8 rows go side by
+    side along the contraction (K = 8 * b1). Each slot sums one nonzero
+    product, so the f32 results are exact; value and index are put back
+    together after the loop. Tie overflow (pos >= kp) matches no kept
+    slot."""
+    n_hi = vals_ref.shape[0]
+    nh = -(-n_hi // 8) * 8              # hi rows padded to the f32 tiling
+    n_pieces = 3 if dtype == jnp.float32 else 1
+    rows = _ROW_GROUP if b0 % _ROW_GROUP == 0 else 1
+    hio = _iota((nh, b1), 0)
+    loio = _iota((_LANES, b1), 0)
+    col = _iota((1, b1), 1)
+    nt = (((1,), (1,)), ((), ()))       # contract the entries (lanes)
+
+    def group_body(g, acc):
+        r0 = g * rows
+        pos = pos_ref[pl.ds(r0, rows), :]               # (rows, b1)
+        sel = mask_ref[pl.ds(r0, rows), :]
+        p_hi = jnp.floor(pos * (1.0 / _LANES))
+        p_lo = pos - _LANES * p_hi
+        pieces = _bf16_pieces(x_rows(r0, rows).astype(jnp.float32), n_pieces)
+        streamed, lo_onehot = [], []
+        for r in range(rows):
+            hi_onehot = jnp.where(p_hi[r:r + 1] == hio, sel[r:r + 1], 0.0)
+            tile_row = jnp.full((1, b1), (r0 + r).astype(jnp.float32))
+            terms = [p[r:r + 1] for p in pieces] + [tile_row, col]
+            streamed.append(jnp.concatenate([hi_onehot * t for t in terms]))
+            lo_onehot.append(jnp.where(p_lo[r:r + 1] == loio, 1.0, 0.0))
+        return acc + _mxu_bf16(jnp.concatenate(streamed, axis=1),
+                               jnp.concatenate(lo_onehot, axis=1), nt)
+
+    acc = jax.lax.fori_loop(
+        0, b0 // rows, group_body,
+        jnp.zeros(((n_pieces + 2) * nh, _LANES), jnp.float32))
+    part = [acc[i * nh:i * nh + n_hi] for i in range(n_pieces + 2)]
+    vals = part[0]
+    if n_pieces == 3:
+        vals = vals + (part[1] + part[2])   # smallest first: exact
+    # the selected entries hold positions 0 .. n_sel - 1: slot s is
+    # filled iff s < n_sel
+    slot = _iota((n_hi, _LANES), 0) * _LANES + _iota((n_hi, _LANES), 1)
+    vals_ref[...] = vals.astype(vals_ref.dtype)
+    idx_ref[...] = jnp.where(slot < n_sel, part[-2] * b1 + part[-1],
+                             -1.0).astype(jnp.int32)
 
 
 def _contract_rows(a, b, acc):
@@ -114,20 +213,43 @@ def _contract_rows(a, b, acc):
     return jnp.sum(out, axis=0)
 
 
-def _flat_positions(mask: jax.Array) -> jax.Array:
-    """Flat-order exclusive position of each True entry, scatter/sort-
-    free: the within-row inclusive cumsum and the row offsets are
-    triangular matmuls (MXU work, no 1D scans), every operand a full
-    (b, b) tile. mask is (b0, b1) f32."""
-    b0, b1 = mask.shape
-    col = _iota((b1, b1), 0)
-    upper = (col <= _iota((b1, b1), 1)).astype(jnp.float32)
-    incl = _dot(mask, upper)                        # (b0, b1)
-    row_count = _dot(mask, jnp.ones((b1, b1), jnp.float32))
-    row = _iota((b0, b0), 0)
-    strict_lower = (_iota((b0, b0), 1) < row).astype(jnp.float32)
-    row_offset = _dot(strict_lower, row_count)      # (b0, b1), per row
-    return row_offset + incl - mask                 # (b0, b1)
+def _compact_f64(x_rows, pos_ref, mask_ref, vals_ref, idx_ref, b0: int,
+                 b1: int):
+    """Compaction of f64 tiles (interpret mode only): per 8-row group,
+    hi_onehot[h, e] (with the value, id or fill flag folded in) times
+    lo_onehot[l, e], contracted over the row, adds that row's entries to
+    the (kp/128, 128) slot grid in f64. Each slot sums one entry + zeros,
+    so the contraction is exact."""
+    n_hi = vals_ref.shape[0]
+    rows = _ROW_GROUP if b0 % _ROW_GROUP == 0 else 1
+    hio = _iota((rows, n_hi, b1), 1)
+    loio = _iota((rows, _LANES, b1), 1)
+    local_ids = _iota((rows, 1, b1), 0) * b1 + _iota((rows, 1, b1), 2)
+
+    def group_body(g, carry):
+        vals, ids, filled = carry
+        r0 = g * rows
+        pos = pos_ref[pl.ds(r0, rows), :][:, None, :]   # (rows, 1, b1)
+        sel = mask_ref[pl.ds(r0, rows), :][:, None, :]
+        p_hi = jnp.floor(pos * (1.0 / _LANES))
+        p_lo = pos - _LANES * p_hi
+        hi_onehot = (p_hi == hio).astype(jnp.float32) * sel
+        lo_onehot = (p_lo == loio).astype(jnp.float32)
+        flat_ids = r0.astype(jnp.float32) * b1 + local_ids
+        xr = x_rows(r0, rows).astype(jnp.float64)[:, None, :]
+        vals = vals + _contract_rows(hi_onehot.astype(jnp.float64) * xr,
+                                     lo_onehot.astype(jnp.float64),
+                                     jnp.float64)
+        ids = ids + _contract_rows(hi_onehot * flat_ids, lo_onehot,
+                                   jnp.float32)
+        filled = filled + _contract_rows(hi_onehot, lo_onehot, jnp.float32)
+        return vals, ids, filled
+
+    zeros = jnp.zeros((n_hi, _LANES), jnp.float32)
+    vals, ids, filled = jax.lax.fori_loop(
+        0, b0 // rows, group_body, (zeros.astype(jnp.float64), zeros, zeros))
+    vals_ref[...] = vals.astype(vals_ref.dtype)
+    idx_ref[...] = jnp.where(filled > 0.0, ids, -1.0).astype(jnp.int32)
 
 
 def _emit_topk_payload(x, x_rows, vals_ref, idx_ref, pos_ref, mask_ref, *,
@@ -156,44 +278,11 @@ def _emit_topk_payload(x, x_rows, vals_ref, idx_ref, pos_ref, mask_ref, *,
                              n_strict + _flat_positions(tie))
     mask_ref[...] = strict + tie
 
-    # slot s = 128 * hi + lo. Per tile row, entry e fills (hi_e, lo_e):
-    # hi_onehot[h, e] (with the value, id or fill flag folded in) times
-    # lo_onehot[l, e], contracted over the row, adds that row's entries
-    # to the (kp/128, 128) slot grid; rows go 8 at a time as one batched
-    # contraction. Each slot sums one entry + zeros, so the contraction
-    # is exact (f64 tiles carry f64 through, in interpret mode); tie
-    # overflow has pos >= kp and matches no slot.
-    n_hi = vals_ref.shape[0]
-    acc = jnp.float64 if x.dtype == jnp.float64 else jnp.float32
-    rows = _ROW_GROUP if b0 % _ROW_GROUP == 0 else 1
-    hio = _iota((rows, n_hi, b1), 1)
-    loio = _iota((rows, _LANES, b1), 1)
-    local_ids = _iota((rows, 1, b1), 0) * b1 + _iota((rows, 1, b1), 2)
-
-    def group_body(g, carry):
-        vals, ids, filled = carry
-        r0 = g * rows
-        pos = pos_ref[pl.ds(r0, rows), :][:, None, :]   # (rows, 1, b1)
-        sel = mask_ref[pl.ds(r0, rows), :][:, None, :]
-        p_hi = jnp.floor(pos * (1.0 / _LANES))
-        p_lo = pos - _LANES * p_hi
-        hi_onehot = (p_hi == hio).astype(jnp.float32) * sel
-        lo_onehot = (p_lo == loio).astype(jnp.float32)
-        flat_ids = r0.astype(jnp.float32) * b1 + local_ids
-        xr = x_rows(r0, rows).astype(acc)[:, None, :]
-        vals = vals + _contract_rows(hi_onehot.astype(acc) * xr,
-                                     lo_onehot.astype(acc), acc)
-        ids = ids + _contract_rows(hi_onehot * flat_ids, lo_onehot,
-                                   jnp.float32)
-        filled = filled + _contract_rows(hi_onehot, lo_onehot, jnp.float32)
-        return vals, ids, filled
-
-    zeros = jnp.zeros((n_hi, _LANES), jnp.float32)
-    vals, ids, filled = jax.lax.fori_loop(
-        0, b0 // rows, group_body, (zeros.astype(acc), zeros, zeros))
-
-    vals_ref[...] = vals.astype(vals_ref.dtype)
-    idx_ref[...] = jnp.where(filled > 0.0, ids, -1.0).astype(jnp.int32)
+    if x.dtype == jnp.float64:
+        _compact_f64(x_rows, pos_ref, mask_ref, vals_ref, idx_ref, b0, b1)
+    else:
+        _compact_bf16(x_rows, pos_ref, mask_ref, vals_ref, idx_ref,
+                      n_strict + jnp.sum(tie), x.dtype, b0, b1)
 
 
 def _topk_payload_tile_kernel(x_ref, vals_ref, idx_ref, pos_ref, mask_ref,
@@ -224,6 +313,9 @@ def _payload_specs(block: int, k: int, gn: int):
     """Per-tile (kp/128, 128) payload blocks of a (nblocks, kp/128, 128)
     output (full trailing dims: a legal TPU block at any k), the two
     (block, block) f32 scratch tiles, and kp."""
+    if block > 2 * _LANES:
+        raise ValueError(f"block {block} > 256: the compaction's counts "
+                         "and coordinates would not be exact in bf16")
     kp = -(-k // _LANES) * _LANES
     row = pl.BlockSpec((None, kp // _LANES, _LANES),
                        lambda i, j: (i * gn + j, 0, 0))

@@ -148,6 +148,131 @@ def test_block_topk_payload_dispatch_oracle_matches_kernel():
     np.testing.assert_array_equal(np.asarray(kv), np.asarray(ov))
 
 
+def _wide_range_tiles(seed, shape, k, cluster, block=128):
+    """f32 entries of magnitude 1e-30..1e30 with random signs, laid out
+    so that the kernel and the sort-based reference keep the same
+    entries: in each tile the k kept entries (ranked at random positions)
+    lie in [1e24, 1e30] and the rest in [1e-30, 1e20] with 10% exact
+    zeros, far apart next to the bisection's resolution (max * 2**-32).
+    With ``cluster``, 48 entries around rank k share the magnitude 1e23
+    (random signs): a tie across the cut, kept in flat order by the
+    kernel as by the stable sort. At k >= block**2 every entry is kept
+    and the magnitudes span the whole range. Entries outside ``shape``
+    are the zero padding the ops add."""
+    rng = np.random.default_rng(seed)
+    m, n = shape
+    gm, gn = -(-m // block), -(-n // block)
+    x = np.zeros((gm * block, gn * block), np.float32)
+    valid = np.zeros(x.shape, bool)
+    valid[:m, :n] = True
+    for i in range(gm):
+        for j in range(gn):
+            win = np.s_[i * block:(i + 1) * block, j * block:(j + 1) * block]
+            spots = rng.permutation(np.flatnonzero(valid[win]))  # by rank
+            mag = 10.0 ** rng.uniform(-30, 20, spots.size)
+            zero = rng.random(spots.size) < 0.1
+            if k >= block * block:
+                mag = 10.0 ** rng.uniform(-30, 30, spots.size)
+            else:
+                top = k - 24 if cluster else k
+                mag[:top] = 10.0 ** rng.uniform(24, 30, top)
+                zero[:top] = False
+                if cluster:
+                    mag[top:k + 24] = 1e23
+                    zero[top:k + 24] = False
+            sign = rng.choice([-1.0, 1.0], spots.size)
+            tile = x[win]
+            tile.flat[spots] = np.where(zero, 0.0, sign * mag)
+            x[win] = tile
+    return x[:m, :n]
+
+
+@pytest.mark.parametrize("op", ["block", "diff"])
+@pytest.mark.parametrize("shape, k, cluster", [
+    ((256, 256), 1024, False),   # n_hi 8: the w8a cell's k
+    ((256, 384), 2048, False),   # n_hi 16: the qwen2 cell's k
+    ((300, 200), 1024, True),    # padding tiles, a tie across the cut
+    ((200, 300), 2048, True),
+    ((130, 140), 16384, False),  # k >= block**2: every entry, in order
+])
+def test_payload_kernels_bitwise_wide_range(op, shape, k, cluster):
+    """The payload kernel body keeps exactly the reference's entries and
+    emits their values bit for bit, on f32 values spanning 1e-30..1e30
+    (the compaction splits each value into bf16 pieces and puts it back
+    together); every tile fills exactly min(k, block**2) slots; the
+    fused op's sumsq is the reference's."""
+    x = jnp.asarray(_wide_range_tiles(k + len(shape) * shape[0], shape, k,
+                                      cluster))
+    m, n = shape
+    xp = jnp.pad(x, ((0, (-m) % 128), (0, (-n) % 128)))
+    if op == "block":
+        vals, idx = block_topk_payload(x, k=k, block=128, use_pallas=True,
+                                       interpret=True)
+        rv, ri = block_topk_payload_ref(xp, k=min(k, 128 * 128), block=128)
+    else:
+        from repro.kernels.block_topk import diff_topk_payload
+        from repro.kernels.block_topk.ref import diff_topk_payload_ref
+
+        # 2x - x == x exactly: the fused diff sees the wide-range values
+        vals, idx, sq = diff_topk_payload(2 * x, x, k=k, block=128,
+                                          use_pallas=True, interpret=True)
+        rv, ri, rsq = diff_topk_payload_ref(2 * xp, xp,
+                                            k=min(k, 128 * 128), block=128)
+        np.testing.assert_allclose(float(sq), float(jnp.sum(rsq)),
+                                   rtol=1e-6)
+    idx, vals = np.asarray(idx), np.asarray(vals)
+    assert ((idx >= 0).sum(axis=1) == min(k, 128 * 128)).all()
+    # slots hold the strict entries, then the boundary ties, each in flat
+    # order; the reference sorts a tile's kept indices
+    if not cluster:
+        np.testing.assert_array_equal(idx, np.asarray(ri))
+    order = np.argsort(idx, axis=1)
+    np.testing.assert_array_equal(np.take_along_axis(idx, order, 1),
+                                  np.asarray(ri))
+    np.testing.assert_array_equal(
+        np.take_along_axis(vals, order, 1).view(np.uint32),
+        np.asarray(rv).view(np.uint32))
+
+
+def test_payload_kernel_empty_slots_are_minus_one():
+    """Slots past the selection's count hold index -1 and value 0. They
+    exist only in the kernel's uncropped (kp/128, 128) blocks: here k =
+    1000 rounds up to kp = 1024, and the selection is the 1000 largest
+    plus the one entry in the bisection's last bracket, so slots 1001 on
+    are empty."""
+    import functools
+
+    from jax.experimental import pallas as pl
+
+    from repro.kernels.block_topk import kernel as K
+
+    k = 1000
+    rng = np.random.default_rng(3)
+    x = np.full(128 * 128, 1e-3, np.float32) * rng.uniform(1, 2, 128 * 128)
+    spots = rng.permutation(128 * 128)
+    x[spots[:k]] = rng.uniform(1, 2, k)
+    x[spots[k]] = 0.5
+    x = jnp.asarray(x.reshape(128, 128))
+    row, scratch, kp = K._payload_specs(128, k, 1)
+    slots = (1, kp // 128, 128)
+    vals, idx = pl.pallas_call(
+        functools.partial(K._topk_payload_tile_kernel, k=k),
+        grid=(1, 1),
+        in_specs=[pl.BlockSpec((128, 128), lambda i, j: (i, j))],
+        out_specs=(row, row),
+        out_shape=(jax.ShapeDtypeStruct(slots, jnp.float32),
+                   jax.ShapeDtypeStruct(slots, jnp.int32)),
+        scratch_shapes=scratch,
+        interpret=True,
+    )(x)
+    vals, idx = np.asarray(vals).reshape(kp), np.asarray(idx).reshape(kp)
+    kept = np.append(np.sort(spots[:k]), spots[k])  # strict, then the tie
+    np.testing.assert_array_equal(idx[:k + 1], kept)
+    np.testing.assert_array_equal(vals[:k + 1],
+                                  np.asarray(x).reshape(-1)[kept])
+    assert (idx[k + 1:] == -1).all() and (vals[k + 1:] == 0).all()
+
+
 # None -> single-block kernel; (8, 128) -> forced tiled kernel (multi-
 # tile grids even on the small test shapes)
 SCATTER_PATHS = [None, (8, 128)]

@@ -9,7 +9,9 @@ machine without the TPU compiler skips these tests and every test
 worker collects the same ones.
 """
 
+import base64
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -109,3 +111,63 @@ def test_diff_topk_payload_compiles(one_chip, shape, k):
     _compile(lambda a, b: diff_topk_payload(a, b, k, use_pallas=True,
                                             interpret=False),
              one_chip, (shape, F32), (shape, F32))
+
+
+def _mosaic_modules(compiled):
+    """The Mosaic module of each ``tpu_custom_call`` in a compiled
+    program, decoded from its ``custom_call_config.body`` (base64 MLIR
+    bytecode)."""
+    from jaxlib.mlir import ir
+    from jaxlib.mlir.passmanager import PassManager
+    from jaxlib.mosaic.python import tpu
+
+    modules = []
+    for body in re.findall(r'"body":"([^"]+)"', compiled.as_text()):
+        ctx = ir.Context()
+        tpu.register_dialect(ctx)
+        ctx.allow_unregistered_dialects = True  # the serialized dialect
+        with ctx, ir.Location.unknown():
+            module = ir.Module.parse(base64.b64decode(body))
+            PassManager.parse(
+                "builtin.module(mosaic-serde{serialize=false})").run(
+                    module.operation)
+            modules.append(module)
+    return modules
+
+
+def _nested_ops(op):
+    for region in op.regions:
+        for block in region.blocks:
+            for inner in block.operations:
+                yield inner.operation
+                yield from _nested_ops(inner.operation)
+
+
+@pytest.mark.parametrize("op", ["block", "diff"])
+@pytest.mark.parametrize("shape, k", [
+    ((300, 300), 1024),     # w8a Hessian diff
+    ((896, 4864), 2048),    # qwen2-0.5b MLP tensor at the training k
+])
+def test_payload_kernel_contractions_single_pass(one_chip, op, shape, k):
+    """For f32 tiles every contraction of the payload kernel is one bf16
+    MXU pass (no ``contract_precision<fp32>``), also inside a program
+    that asks for the highest matmul precision (as the FedNL cells do),
+    and the compaction loop holds exactly one ``tpu.matmul``; the
+    bisection loop holds none."""
+    with jax.default_matmul_precision("highest"):
+        if op == "block":
+            compiled = _compile(lambda x: block_topk_payload(
+                x, k, use_pallas=True, interpret=False),
+                one_chip, (shape, F32))
+        else:
+            compiled = _compile(lambda a, b: diff_topk_payload(
+                a, b, k, use_pallas=True, interpret=False),
+                one_chip, (shape, F32), (shape, F32))
+    (module,) = _mosaic_modules(compiled)
+    ops = list(_nested_ops(module.operation))
+    matmuls = [o for o in ops if o.name == "tpu.matmul"]
+    assert matmuls
+    assert not any("contract_precision<fp32>" in str(o) for o in matmuls)
+    per_loop = sorted(sum(o.name == "tpu.matmul" for o in _nested_ops(loop))
+                      for loop in ops if loop.name == "scf.for")
+    assert per_loop == [0, 1]
